@@ -9,6 +9,8 @@ import pkgutil
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 #: benchmark-package modules that are not runnable panels
 EXCLUDED = {"common", "run"}
 
@@ -53,14 +55,26 @@ def main() -> None:
         ("hillclimb", hillclimb),
     ]
     _audit(modules)
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = run_panels(modules, quick)
+    if failed:
+        sys.exit(f"# {len(failed)} panel(s) failed: {', '.join(failed)}")
+
+
+def run_panels(modules, quick: bool = False) -> list:
+    """Run every panel; a failing panel is reported and the rest still
+    run.  Returns the names of the panels that failed."""
+    failed = []
     for name, mod in modules:
         t0 = time.time()
         try:
             mod.run(quick=quick)
             print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — later panels still run
+            failed.append(name)
             print(f"# {name} FAILED: {type(e).__name__}: {e}", flush=True)
+    return failed
 
 
 if __name__ == '__main__':

@@ -9,6 +9,7 @@ import argparse
 import jax.numpy as jnp
 
 from .common import save_json
+from repro.compile_cache import enable_compile_cache
 from repro.configs import CONFIGS
 from repro.models import core as M
 from repro.serving.engine import Request, ServeEngine
@@ -103,6 +104,7 @@ if __name__ == "__main__":
     ap.add_argument("--skip-poll", action="store_true",
                     help="co-residency panel only (no jitted serving)")
     a = ap.parse_args()
+    enable_compile_cache()
     if not a.skip_poll:
         run(quick=a.quick)
     co_residency(quick=a.quick)

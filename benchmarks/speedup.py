@@ -4,6 +4,7 @@ wall-clock per CoreMark iteration, plus modelled-time throughput."""
 from __future__ import annotations
 
 from .common import parse_kv, run_workload, save_json
+from repro.compile_cache import enable_compile_cache
 
 
 def run(quick=False):
@@ -27,4 +28,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

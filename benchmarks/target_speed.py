@@ -59,6 +59,7 @@ import time
 import numpy as np
 
 from .common import load_json, save_json
+from repro.compile_cache import enable_compile_cache
 from repro.configs.fase_rocket import target_kwargs
 from repro.configs.registry import FASE_ROCKET
 from repro.core.interface import JaxTarget
@@ -216,4 +217,5 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(quick="--quick" in sys.argv)

@@ -71,12 +71,11 @@ xlstm_350m = _add(ModelConfig(
 # The target_* knobs drive the JaxTarget fast-path interpreter
 # (repro.core.target.cpu.run_chunk_fast): batched-issue width, fetch-block
 # size, block-cache enable, and the translate/fetch kernel backend for
-# block fills ("ref" jnp oracle | "pallas"); they trade host speed and
-# compile time only — every setting is bit-identical to PySim.  On CPU
-# the block cache and the no-cache vector path measure within ~10% of
-# each other (results/target_speed.json records both); the cache stays
-# on because the Pallas fill path's contiguous block DMA is the
-# accelerator-side win.
+# block fills ("ref" jnp oracle on every backend | "pallas", CPU
+# interpret mode only: it does not lower for the TPU); they trade host
+# speed and compile time only — every setting is bit-identical to
+# PySim.  On CPU the block cache and the no-cache vector path measure
+# within ~10% of each other (results/target_speed.json records both).
 # The telem_* knobs provision the out-of-band telemetry lane
 # (repro.telemetry): counter-sample cadence, the fraction of link
 # bandwidth the side-band lane is granted, the commit-trace ring depth

@@ -26,6 +26,7 @@ from typing import Protocol
 
 import numpy as np
 
+from ..kernels.page_walk.ops import check_pallas_backend
 from .target import cpu as _cpu
 
 import jax
@@ -184,7 +185,8 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         instruction fetch,
       * ``fetch_kernel`` — ``"ref"`` (jnp oracle) or ``"pallas"`` for
         the block-fill translate/fetch chain
-        (:mod:`repro.kernels.page_walk`),
+        (:mod:`repro.kernels.page_walk`); ``"pallas"`` runs in interpret
+        mode on the CPU backend only and is refused here on any other,
       * ``dtlb_ways`` — per-lane data-translation cache ways in the fast
         path (power of 2; 0 disables and re-walks every load/store).
     """
@@ -194,6 +196,8 @@ PySim` — the knobs trade compile time and host speed, never semantics:
                  issue_width: int = 8, block_words: int = 16,
                  block_cache: bool = True, fetch_kernel: str = "ref",
                  dtlb_ways: int = 8):
+        if fast_path and fetch_kernel == "pallas":
+            check_pallas_backend()
         self.nc = n_cores
         self.mem_bytes = mem_bytes
         self.chunk_cycles = chunk_cycles

@@ -1099,8 +1099,9 @@ def _run_chunk_fast(st: CpuState, n_cores: int, mem_bytes: int, max_cycles,
     ``block_cache=False`` keeps the batched vector issue but re-walks the
     fetch for every instruction.  ``fetch_kernel`` picks the translate/
     fetch-gather backend for block fills: ``"ref"`` (pure-jnp oracle,
-    the CPU default) or ``"pallas"`` (the interpret-capable Pallas
-    kernel, native on TPU).  ``trigger`` (static, a hashable trigger
+    the default on every backend) or ``"pallas"`` (the Pallas kernel in
+    interpret mode, CPU backend only: it does not lower for the TPU).
+    ``trigger`` (static, a hashable trigger
     spec from :mod:`repro.telemetry.triggers`) windows commit-trace
     capture; it only affects which records enter the ring — never the
     architectural step — and ``None`` compiles the gate out.
@@ -1121,17 +1122,11 @@ def _run_chunk_fast(st: CpuState, n_cores: int, mem_bytes: int, max_cycles,
     limit = jnp.asarray(max_cycles, U64)
 
     if fetch_kernel == "pallas":
-        interpret = jax.default_backend() != "tpu"
-
         def walk_fetch(mem, satp, va, base=None):
             assert base is None, "pallas fetch is single-device only"
             return pw_ops.walk_fetch_block(mem, satp, va, mem_bytes - 1,
-                                           block_words,
-                                           interpret=interpret)
+                                           block_words)
     else:
-        # "ref" must be honourable on every backend (the Pallas kernel's
-        # u64 image needs an x64 story real TPUs lack), so bypass the
-        # backend-dispatching ops layer entirely
         def walk_fetch(mem, satp, va, base=None):
             return pw_ref.walk_fetch_block_ref(mem, satp, va, mask,
                                                block_words, base)

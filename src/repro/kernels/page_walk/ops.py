@@ -1,9 +1,11 @@
-"""Jit'd wrappers choosing the Pallas kernel on TPU, jnp reference on CPU.
+"""Dispatch for the Sv39 walk and the fetch-block fill.
 
-Same dispatch contract as the other kernel packages: ``interpret=True``
-forces the Pallas path through the interpreter (CPU tests and the
-``fetch_kernel="pallas"`` target config), otherwise the kernel only runs
-on a real TPU backend and CPU hosts use the pure-jnp oracle.
+The data-side walk is always the pure-jnp oracle.  The Pallas fill
+kernel runs only in interpret mode on the CPU backend: its memory image
+is u64 words, and Mosaic has no 64-bit integer support, so it does not
+lower for the TPU.  Targets that ask for it on another backend are
+refused by :func:`check_pallas_backend` when they are built, before any
+lowering.
 """
 from __future__ import annotations
 
@@ -13,8 +15,14 @@ from . import page_walk as K
 from . import ref as R
 
 
-def _use_kernel(interpret):
-    return interpret or jax.default_backend() == "tpu"
+def check_pallas_backend() -> None:
+    """Raise unless the Pallas fill kernel can run on this backend."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise NotImplementedError(
+            f"fetch_kernel='pallas' does not lower for the {backend} "
+            "backend: the memory image is u64 words and Mosaic has no "
+            "64-bit integer support; use fetch_kernel='ref'")
 
 
 def sv39_walk(mem, satp, va, want_write, want_exec, mask):
@@ -24,8 +32,8 @@ def sv39_walk(mem, satp, va, want_write, want_exec, mask):
     return R.sv39_walk_ref(mem, satp, va, want_write, want_exec, mask)
 
 
-def walk_fetch_block(mem, satp, va, mask, block_words, interpret=False):
-    if _use_kernel(interpret):
-        return K.walk_fetch_block(mem, satp, va, mask, block_words,
-                                  interpret=interpret)
-    return R.walk_fetch_block_ref(mem, satp, va, mask, block_words)
+def walk_fetch_block(mem, satp, va, mask, block_words):
+    """The Pallas fill kernel, in interpret mode on the CPU backend."""
+    check_pallas_backend()
+    return K.walk_fetch_block(mem, satp, va, mask, block_words,
+                              interpret=True)

@@ -7,12 +7,13 @@ behind the translated pc and the 32-bit instruction slots are carved out
 in VMEM.  ``satp``/``va`` ride the scalar-prefetch operand, the same
 mechanism the page-ops kernels use for their block-table indirection.
 
-The memory image is u64 words, so on real TPU hardware this kernel needs
-the x64 story Mosaic currently lacks — it is exercised in interpret mode
-on CPU (``tests/test_kernels.py``) and kept in the ops/ref/impl layout so
-the TPU path can slot in without touching callers.  The pure-jnp oracle
-(:mod:`repro.kernels.page_walk.ref`) is the production backend on CPU
-hosts, selected by :mod:`repro.kernels.page_walk.ops`.
+This kernel does not lower for the TPU today: the memory image is u64
+words and Mosaic has no 64-bit integer support (a v5e compile is refused
+during lowering).  It runs only in interpret mode on the CPU backend
+(``tests/test_kernels.py``), and :mod:`repro.kernels.page_walk.ops`
+refuses ``fetch_kernel="pallas"`` on any other backend when the target
+is built.  The pure-jnp oracle (:mod:`repro.kernels.page_walk.ref`) is
+the fill path on every backend, the TPU included.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Chip smoke test: FASE's main path once on one TPU, checked against PySim.
 
-    python chip_smoke.py [--bc-scale S]
+    python chip_smoke.py
 
 Everything runs in this one process, at ``FASE_ROCKET`` width (4 cores,
 64 MiB memory image), through the entry points a user calls:
@@ -9,18 +9,20 @@ Everything runs in this one process, at ``FASE_ROCKET`` width (4 cores,
             fallback); print its kind and the device count;
   coremark  one CoreMark iteration, 1 thread, on ``JaxTarget`` over the UART
             (``FASE_ROCKET``), ``FaseRuntime(mode="fase")``;
-  bc        GAPBS bc, 4 threads, on ``graphgen.rmat(S, 16)`` (degree 16
-            is GAPBS's own generator default) over PCIe
+  bc        GAPBS bc, 4 threads, on ``graphgen.rmat(14, 16)`` (degree
+            16 is GAPBS's own generator default) over PCIe
             (``FASE_ROCKET_PCIE``);
   fleet     4 boards of ``FASE_FLEET_VMAP`` on the one chip, stepped by
             ``FleetRuntime.run_synchronous``: boards 0 and 2 run the
             CoreMark job over the UART, boards 1 and 3 the bc job over
-            PCIe (the config's per-board link mix).
+            PCIe.  The config puts every board on PCIe; the links are set
+            here so each board matches its solo run.
 
 Each phase runs the same jobs on ``PySim`` and requires stdout, ticks,
 per-core instret and ``traffic_total`` to be bit-identical; CoreMark's
-own CRC must match, and the fleet must take one dispatch per global
-chunk.  Any mismatch or exception exits non-zero.
+own CRC must match.  In the fleet every kernel dispatch must advance
+every board whose job is still running, so that there is one dispatch
+per global chunk.  Any mismatch or exception exits non-zero.
 
 Per phase it prints compile seconds (JAX tracing, lowering and XLA
 compilation, from ``jax.monitoring``), run seconds (the rest of the
@@ -31,7 +33,6 @@ reading, not a benchmark.  The last line of stdout is
 """
 from __future__ import annotations
 
-import argparse
 import gc
 import json
 import sys
@@ -43,6 +44,9 @@ COREMARK_CRC = 16356
 COREMARK_ITERS = 1
 #: GAPBS's Kronecker generator default degree
 GAPBS_DEGREE = 16
+#: rmat scale of the bc graph: the largest that keeps the whole script
+#: within about 10 minutes on one v5e (readings in PERF.md, Findings)
+BC_SCALE = 14
 BC_THREADS = 4
 MAX_TICKS = 1 << 44
 COMPARED = ("stdout", "ticks", "instret", "traffic_total")
@@ -170,11 +174,11 @@ def fleet_phase(clock, jobs, refs):
     ft = fleet.fleet_target
     assert ft.n_devices == len(jobs)
     jax.block_until_ready(ft.sts)
-    boards_per_dispatch = []
+    advanced = []        # per kernel dispatch: which boards had cycles
     kernel = cpu.run_chunk_fleet
 
     def counted(sts, n_cores, mem_bytes, budgets, *args):
-        boards_per_dispatch.append(int(np.count_nonzero(budgets)))
+        advanced.append(np.asarray(budgets) != 0)
         return kernel(sts, n_cores, mem_bytes, budgets, *args)
 
     cpu.run_chunk_fleet = counted
@@ -188,26 +192,31 @@ def fleet_phase(clock, jobs, refs):
         cpu.run_chunk_fleet = kernel
     for i, (r, ref) in enumerate(zip(res, refs)):
         check_same(ref, r.report, f"fleet board {i}")
-    if ft.dispatch_count != len(boards_per_dispatch):
+    # A board's job runs from the first dispatch to the last that gave it
+    # cycles.  One dispatch per global chunk means every dispatch advanced
+    # exactly the boards still running, so the dispatches number as many
+    # as the chunks of the longest job.
+    adv = np.array(advanced)
+    last = np.array([np.flatnonzero(col).max() for col in adv.T])
+    running = np.arange(len(adv))[:, None] <= last[None, :]
+    chunks = adv.sum(axis=0)
+    if not (adv == running).all():
+        k = int(np.flatnonzero((adv != running).any(axis=1))[0])
         raise AssertionError(
-            f"fleet: {ft.dispatch_count} global chunks took "
-            f"{len(boards_per_dispatch)} kernel dispatches")
-    if max(boards_per_dispatch) != len(jobs):
-        raise AssertionError("fleet: no dispatch advanced every board")
+            f"fleet: dispatch {k} advanced boards {np.flatnonzero(adv[k])}"
+            f" while boards {np.flatnonzero(running[k])} were running")
+    if not len(adv) == ft.dispatch_count == chunks.max():
+        raise AssertionError(
+            f"fleet: {len(adv)} kernel dispatches, dispatch_count "
+            f"{ft.dispatch_count}, longest job {chunks.max()} chunks")
     report("fleet", clock, c0, wall,
            sum(sum(r.report.instret) for r in res), None,
-           dict(links=links, global_chunks=ft.dispatch_count,
-                dispatches=len(boards_per_dispatch),
+           dict(links=links, global_chunks=int(chunks.max()),
+                dispatches=len(adv), chunks_per_board=chunks.tolist(),
                 ticks=[r.report.ticks for r in res]))
 
 
-def main(argv=None) -> None:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--bc-scale", type=int, default=14,
-                    help="rmat scale of the bc graph (at least 9)")
-    args = ap.parse_args(argv)
-    if args.bc_scale < 9:
-        ap.error("--bc-scale must be at least 9")
+def main() -> None:
     dev = device_check()
 
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -223,10 +232,9 @@ def main(argv=None) -> None:
     check_coremark(cm_ref)
     gc.collect()
 
-    print(f"bc graph: rmat scale {args.bc_scale}, degree {GAPBS_DEGREE}",
+    print(f"bc graph: rmat scale {BC_SCALE}, degree {GAPBS_DEGREE}",
           flush=True)
-    graph = {"g.bin": graphgen.rmat(args.bc_scale, GAPBS_DEGREE,
-                                    weights=True)}
+    graph = {"g.bin": graphgen.rmat(BC_SCALE, GAPBS_DEGREE, weights=True)}
     bc_argv = ["g.bin", str(BC_THREADS), "1"]
     bc_ref = solo_phase("bc", clock, FASE_ROCKET_PCIE, "bc", bc_argv, graph)
     gc.collect()

@@ -3,9 +3,9 @@
 The data-side walk is always the pure-jnp oracle.  The Pallas fill
 kernel runs only in interpret mode on the CPU backend: its memory image
 is u64 words, and Mosaic has no 64-bit integer support, so it does not
-lower for the TPU.  Targets that ask for it on another backend are
-refused by :func:`check_pallas_backend` when they are built, before any
-lowering.
+lower for the TPU.  ``JaxTarget``, the one target that can ask for it
+(the fleet kernel is single-device only for this fill), calls
+:func:`check_pallas_backend` when it is built, before any lowering.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ def sv39_walk(mem, satp, va, want_write, want_exec, mask):
 
 
 def walk_fetch_block(mem, satp, va, mask, block_words):
-    """The Pallas fill kernel, in interpret mode on the CPU backend."""
-    check_pallas_backend()
+    """The Pallas fill kernel, in interpret mode (CPU backend only)."""
     return K.walk_fetch_block(mem, satp, va, mask, block_words,
                               interpret=True)

@@ -12,7 +12,7 @@ import time
 from repro.compile_cache import enable_compile_cache
 
 #: benchmark-package modules that are not runnable panels
-EXCLUDED = {"common", "run"}
+EXCLUDED = {"common", "host_spans", "run"}
 
 
 def _audit(modules) -> None:
@@ -31,10 +31,10 @@ def _audit(modules) -> None:
 def main() -> None:
     quick = "--quick" in sys.argv
     from . import (arg_prefetch, baud_sweep, coremark_accuracy,
-                   fleet_scale, gapbs_accuracy, hfutex_bench, hillclimb,
-                   htp_vs_direct, migration, net_scale, roofline,
-                   scale_sweep, serving_traffic, speedup,
-                   stall_attribution, stall_breakdown, target_speed)
+                   fleet_scale, gapbs_accuracy, hfutex_bench,
+                   htp_vs_direct, migration, net_scale, scale_sweep,
+                   serving_traffic, speedup, stall_attribution,
+                   stall_breakdown, target_speed)
     modules = [
         ("target_speed", target_speed),
         ("htp_vs_direct", htp_vs_direct),
@@ -50,9 +50,7 @@ def main() -> None:
         ("fleet_scale", fleet_scale),
         ("net_scale", net_scale),
         ("migration", migration),
-        ("roofline", roofline),
         ("stall_attribution", stall_attribution),
-        ("hillclimb", hillclimb),
     ]
     _audit(modules)
     enable_compile_cache()

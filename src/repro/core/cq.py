@@ -53,6 +53,7 @@ from __future__ import annotations
 from collections import deque
 from dataclasses import dataclass, field
 
+from . import spans
 from .session import (HtpSession, HtpTransaction, TransactionResult)
 
 #: default bound on retained completions (older entries are dropped; the
@@ -209,27 +210,22 @@ class AsyncHtpSession(HtpSession):
         if not txn.requests:          # nothing crosses the wire
             return TransactionResult(done=ready)
         ch = self.channel
-        if not (ch.enabled and ch.pipelined):
-            # serial link: the synchronous arithmetic is the model, and
-            # staying byte-for-byte on it is the UART timing contract.
-            if self.trace is None:
-                res = super().submit(txn, ready)
+        with spans.span("sess:submit"):
+            if not (ch.enabled and ch.pipelined):
+                # serial link: the synchronous arithmetic is the model, and
+                # staying byte-for-byte on it is the UART timing contract.
+                res = self._send(txn, ready)
+                issue = wire_start = ready
             else:
-                # record once, below, with the completion token attached
-                self._trace_suspend = True
-                try:
-                    res = super().submit(txn, ready)
-                finally:
-                    self._trace_suspend = False
-            issue = wire_start = ready
-        else:
-            res, issue, wire_start = self._submit_pipelined(txn, ready, s)
-        s.seq += 1
-        s.last_issue = max(s.last_issue, issue)
-        res.token = CompletionToken(stream, s.seq, res.done)
-        s.last_token = res.token
-        self.cq.push(Completion(res.token, issue, wire_start, res.done,
-                                len(txn), txn.wire_bytes(self.direct_mode)))
+                res, issue, wire_start = self._submit_pipelined(txn, ready,
+                                                                s)
+            s.seq += 1
+            s.last_issue = max(s.last_issue, issue)
+            res.token = CompletionToken(stream, s.seq, res.done)
+            s.last_token = res.token
+            self.cq.push(Completion(res.token, issue, wire_start, res.done,
+                                    len(txn),
+                                    txn.wire_bytes(self.direct_mode)))
         if self.trace is not None:
             self.trace.on_submit(stream, txn, deps, at, ready, res)
         return res

@@ -27,6 +27,7 @@ from typing import Protocol
 import numpy as np
 
 from ..kernels.page_walk.ops import check_pallas_backend
+from . import spans
 from .target import cpu as _cpu
 
 import jax
@@ -230,17 +231,21 @@ PySim` — the knobs trade compile time and host speed, never semantics:
             self.st = _cpu.run_chunk(self.st, self.nc, self.mem_bytes,
                                      budget)
 
+    @spans.traced("acc:redirect")
     def redirect(self, c, pc, resume_tick=0):
         # one donated jitted dispatch, not four eager scatters
         self.st = _cpu.redirect_op(self.st, np.int32(c), np.uint64(pc),
                                    np.uint64(max(resume_tick, 0)))
 
+    @spans.traced("acc:park")
     def park(self, c):
         self.st = _cpu.park_op(self.st, np.int32(c))
 
+    @spans.traced("sync:pending_cores")
     def pending_cores(self):
         return list(np.nonzero(np.asarray(self.st.pending))[0])
 
+    @spans.traced("acc:clear_pending")
     def clear_pending(self, c):
         self.st = _cpu.clear_pending_op(self.st, np.int32(c))
 
@@ -250,9 +255,11 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         # times cheaper than an eager un-jitted __getitem__
         return self.fetch_batch(csrs=[(c, name)])[1][0]
 
+    @spans.traced("sync:get_priv")
     def get_priv(self, c):
         return int(np.asarray(self.st.priv[c]))
 
+    @spans.traced("acc:csr_write")
     def csr_write(self, c, name, v):
         """Host-side CSR/core-state write (CsrW's device half; snapshot
         restore).  Each field keeps its device dtype; ``ticks`` is the
@@ -260,6 +267,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         self.st = _cpu.csr_write_op(self.st, name, np.int32(c),
                                     np.uint64(v & ((1 << 64) - 1)))
 
+    @spans.traced("acc:set_satp")
     def set_satp(self, c, v):
         self.st = _cpu.csr_write_op(self.st, "satp", np.int32(c),
                                     np.uint64(v))
@@ -276,6 +284,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
     def reg_read(self, c, idx):
         return self.fetch_batch(regs=[(c, idx)])[0][0]
 
+    @spans.traced("sync:fetch_batch")
     def fetch_batch(self, regs=(), csrs=(), words=()):
         """Batched host reads: ONE blocking device sync for any mix of
         GPRs (``(core, idx)`` pairs), CSR/core-state fields
@@ -297,12 +306,14 @@ PySim` — the knobs trade compile time and host speed, never semantics:
             self.st, names, reg_cpu, reg_idx, word_idx, csr_cpus))
         return unpack_read_batch(got, len(regs), len(words), names, order)
 
+    @spans.traced("acc:reg_write")
     def reg_write(self, c, idx, v):
         if idx != 0:
             self.st = _cpu.reg_write_op(self.st, np.int32(c),
                                         np.int32(idx),
                                         np.uint64(v & ((1 << 64) - 1)))
 
+    @spans.traced("acc:commit_batch")
     def commit_batch(self, regs=(), csrs=(), words=()):
         """Batched host writes: ONE donated device update for any mix of
         GPRs (``(core, idx, val)``), CSR/core-state fields
@@ -324,42 +335,51 @@ PySim` — the knobs trade compile time and host speed, never semantics:
     def mem_read_word(self, pa):
         return self.fetch_batch(words=[pa])[2][0]
 
+    @spans.traced("acc:mem_write_word")
     def mem_write_word(self, pa, v):
         self.st = self.st._replace(
             mem=_cpu.mem_write_words(self.st.mem,
                                      jnp.asarray([pa >> 3]),
                                      jnp.asarray([v], dtype=jnp.uint64)))
 
+    @spans.traced("sync:page_read")
     def page_read(self, ppn):
         return np.asarray(_cpu.page_read_words(self.st.mem,
                                                (ppn << 12) >> 3))
 
+    @spans.traced("acc:page_write")
     def page_write(self, ppn, words):
         w = jnp.asarray(np.ascontiguousarray(words, dtype=np.uint64))
         self.st = self.st._replace(
             mem=_cpu.page_write_words(self.st.mem, (ppn << 12) >> 3, w))
 
+    @spans.traced("acc:page_set")
     def page_set(self, ppn, val):
         self.st = self.st._replace(
             mem=_cpu.page_set_words(self.st.mem, (ppn << 12) >> 3,
                                     np.uint64(val)))
 
+    @spans.traced("acc:page_copy")
     def page_copy(self, src_ppn, dst_ppn):
         self.st = self.st._replace(
             mem=_cpu.page_copy_words(self.st.mem, (src_ppn << 12) >> 3,
                                      (dst_ppn << 12) >> 3))
 
     # -- perf --------------------------------------------------------------
+    @spans.traced("sync:get_ticks")
     def get_ticks(self):
         return int(np.asarray(self.st.ticks))
 
+    @spans.traced("sync:get_uticks")
     def get_uticks(self, c):
         return int(np.asarray(self.st.uticks[c]))
 
+    @spans.traced("sync:get_instret")
     def get_instret(self, c):
         return int(np.asarray(self.st.instret[c]))
 
     # -- telemetry: commit-trace ring (repro.telemetry) --------------------
+    @spans.traced("acc:trace_arm")
     def trace_arm(self, slots):
         """Arm per-core commit-trace capture: rebuilds the carry with a
         ``(nc, slots, 4)`` ring so the next ``run`` compiles the
@@ -375,6 +395,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
         self._trace_base = [0] * self.nc
 
+    @spans.traced("acc:trace_trigger")
     def trace_trigger(self, spec):
         """Install (or clear) the capture-window predicate — a hashable
         trigger spec tuple (see :mod:`repro.telemetry.triggers`) that
@@ -385,6 +406,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         self.st = self.st._replace(
             trace_armed=jnp.zeros((self.nc,), jnp.bool_))
 
+    @spans.traced("sync:trace_drain")
     def trace_drain(self, c=None, limit=None):
         """Drain commit-trace rings, mirroring
         :meth:`repro.core.target.pysim.PySim.trace_drain` bit-for-bit:

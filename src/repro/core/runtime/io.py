@@ -10,8 +10,9 @@ scheduler pass.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
+from .. import spans
 from .vm import FileImage
 
 
@@ -116,6 +117,7 @@ class AsyncHostIO:
     def submit_read(self, tid: int, fd: int, count: int, callback):
         self.parked.append((tid, fd, count, callback))
 
+    @spans.traced("rt:poll")
     def poll(self):
         still = []
         for tid, fd, count, cb in self.parked:

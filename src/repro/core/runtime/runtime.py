@@ -30,6 +30,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 from .. import channel as chmod
+from .. import spans
 from ..cq import AsyncHtpSession
 from ..hfutex import HFutexCache
 from ..session import HtpSession, HtpTransaction
@@ -154,6 +155,7 @@ class FaseRuntime:
         self.exit_code = 0
 
     # ------------------------------------------------------------------
+    @spans.traced("rt:load")
     def load(self, image, argv: list[str], stdin: bytes = b"",
              files: dict[str, bytes] | None = None):
         for name, data in (files or {}).items():
@@ -315,6 +317,7 @@ class FaseRuntime:
         self.schedule_onto(cpu, t)
 
     # ---------------- exception loop ------------------------------------
+    @spans.traced("rt:dispatch")
     def _dispatch_ready(self, now: int):
         idle = [c for c in range(self.target.n_cores)
                 if c not in self.sched.running]
@@ -335,6 +338,7 @@ class FaseRuntime:
             self.switch_in(cpu, th, max(now, th.ready_at,
                                         self.session.channel.busy_until))
 
+    @spans.traced("rt:exception")
     def _handle_exception(self, cpu: int, now: int):
         self.stats["exceptions"] += 1
         thread = self.sched.current(cpu)
@@ -347,7 +351,8 @@ class FaseRuntime:
         # both CSRs in one batched device sync, not two round trips
         _, (cause, epc), _ = self.target.fetch_batch(
             csrs=[(cpu, "mcause"), (cpu, "mepc")])
-        done = self.session.try_hfutex_fast_path(cpu, cause, epc, now)
+        with spans.span("rt:hfutex"):
+            done = self.session.try_hfutex_fast_path(cpu, cause, epc, now)
         if done is not None:
             self.stats["hfutex_hits"] += 1
             return
@@ -363,33 +368,37 @@ class FaseRuntime:
             sysmod.dispatch(self, cpu, thread, epc, t)
             return
         if cause in (12, 13, 15):
-            self.stats["page_fault_exceptions"] += 1
-            access = {12: "x", 13: "r", 15: "w"}[cause]
-            pages_before = self.vm.stats["pages_mapped"]
-            try:
-                t2 = self.vm.handle_fault(tval, access, cpu, t)
-            except SegFault as e:
-                raise TargetCrash(
-                    f"cpu{cpu} tid{thread.tid}: {e} pc={epc:#x}") from None
-            if self.mode == "oracle":
-                npages = self.vm.stats["pages_mapped"] - pages_before
-                kc = sysmod.KERNEL_COST["page_fault"] + \
-                    sysmod.KERNEL_COST["page_fault_per_page"] * max(npages, 1)
-                self.stats["kernel_ticks"] += kc
-                t2 = t + kc
-            else:
-                n_req = 0
-                host = int((self.host_base_us +
-                            self.host_us_per_req * 2) * self.ticks_per_us)
-                self.stats["runtime_ticks"] += host
-                t2 += host
-            # the resume explicitly depends on the fault batch's token
-            self.session.submit(
-                HtpTransaction().redirect(cpu, epc, "pagefault"), t2,
-                stream=cpu, deps=(self.vm.last_token,))
+            self._handle_page_fault(cpu, thread, cause, epc, tval, t)
             return
         raise TargetCrash(f"cpu{cpu} tid{thread.tid}: cause={cause} "
                           f"epc={epc:#x} tval={tval:#x}")
+
+    @spans.traced("rt:pagefault")
+    def _handle_page_fault(self, cpu: int, thread, cause: int, epc: int,
+                           tval: int, t: int):
+        self.stats["page_fault_exceptions"] += 1
+        access = {12: "x", 13: "r", 15: "w"}[cause]
+        pages_before = self.vm.stats["pages_mapped"]
+        try:
+            t2 = self.vm.handle_fault(tval, access, cpu, t)
+        except SegFault as e:
+            raise TargetCrash(
+                f"cpu{cpu} tid{thread.tid}: {e} pc={epc:#x}") from None
+        if self.mode == "oracle":
+            npages = self.vm.stats["pages_mapped"] - pages_before
+            kc = sysmod.KERNEL_COST["page_fault"] + \
+                sysmod.KERNEL_COST["page_fault_per_page"] * max(npages, 1)
+            self.stats["kernel_ticks"] += kc
+            t2 = t + kc
+        else:
+            host = int((self.host_base_us +
+                        self.host_us_per_req * 2) * self.ticks_per_us)
+            self.stats["runtime_ticks"] += host
+            t2 += host
+        # the resume explicitly depends on the fault batch's token
+        self.session.submit(
+            HtpTransaction().redirect(cpu, epc, "pagefault"), t2,
+            stream=cpu, deps=(self.vm.last_token,))
 
     def run(self, max_ticks: int = 1 << 48,
             max_exceptions: int = 1 << 30) -> Report:
@@ -398,6 +407,7 @@ class FaseRuntime:
         assert rep is not None
         return rep
 
+    @spans.traced("rt:run")
     def run_slice(self, pause_ticks: int | None,
                   max_ticks: int = 1 << 48,
                   max_exceptions: int = 1 << 30) -> Report | None:
@@ -426,8 +436,9 @@ class FaseRuntime:
                     f"{ {k: list(v) for k, v in self.sched.futex_q.items()} }")
             budget = 1 << 62 if pause_ticks is None \
                 else max(pause_ticks - now, 1)
-            self.target.run(budget)
-            now = self.target.get_ticks()  # analysis: allow-host-sync
+            with spans.span("chunk"):
+                self.target.run(budget)
+                now = self.target.get_ticks()  # analysis: allow-host-sync
             if self.traffic_hook is not None:
                 self.traffic_hook(now)
             if self.telemetry is not None:
@@ -468,8 +479,11 @@ class FaseRuntime:
         """Host phase after a fleet global chunk: pump telemetry and
         handle every exception the chunk raised, restoring the same
         loop-boundary invariant :meth:`run_slice` keeps (all raised
-        exceptions handled, no half-applied host work)."""
-        now = self.target.get_ticks()  # analysis: allow-host-sync
+        exceptions handled, no half-applied host work).  The fleet has
+        launched the chunk and waited on it; the clock read here is this
+        board's end of it (``fase:chunk``)."""
+        with spans.span("chunk"):
+            now = self.target.get_ticks()  # analysis: allow-host-sync
         if self.traffic_hook is not None:
             self.traffic_hook(now)
         if self.telemetry is not None:
@@ -498,6 +512,7 @@ class FaseRuntime:
         if self.telemetry is not None:
             self.telemetry.rebind(session)
 
+    @spans.traced("rt:finish")
     def finish(self) -> Report:
         # flush telemetry first: a final forced counter sample + ring
         # drain on the telem lane (side-band — cannot move the harvest)

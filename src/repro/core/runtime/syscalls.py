@@ -13,6 +13,7 @@ the arguments its handler actually consumes.
 """
 from __future__ import annotations
 
+from .. import spans
 from ..session import HtpTransaction
 from . import vm as vmod
 from .vm import MAP_ANON, MAP_SHARED, PAGE, PROT_READ, PROT_WRITE
@@ -57,6 +58,7 @@ class SyscallError(Exception):
     pass
 
 
+@spans.traced("rt:syscall")
 def dispatch(rt, cpu: int, thread, epc: int, t0: int) -> None:
     """Handle the ecall raised by ``thread`` on ``cpu`` trapped at ``t0``."""
     # snapshot the request counter BEFORE the a7 read: the host-latency
@@ -85,7 +87,8 @@ def dispatch(rt, cpu: int, thread, epc: int, t0: int) -> None:
     args.t = t
     args.req0 = req0
     fn = _HANDLERS.get(name, _sys_enosys)
-    fn(rt, cpu, thread, epc, args)
+    with spans.span(f"rt:sys:{name}"):
+        fn(rt, cpu, thread, epc, args)
 
 
 class _ArgReader:

@@ -48,7 +48,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-from . import htp
+from . import htp, spans
 from .channel import Channel, UartChannel
 from .hfutex import HFutexCache
 
@@ -272,10 +272,8 @@ class HtpSession:
         # analysis trace hook (repro.analysis.trace.TraceRecorder).  None
         # by default: the only cost of the disabled hook is one
         # ``is not None`` test per submit, so golden ticks and wall-clock
-        # are untouched.  ``_trace_suspend`` lets the async layer delegate
-        # to this submit without double-recording.
+        # are untouched.
         self.trace = None
-        self._trace_suspend = False
         # write stage of the submit in flight (None outside one); see
         # _WriteStage — direct accessor calls between transactions (the
         # hfutex fast path, fleet migration) never see a live stage
@@ -295,6 +293,15 @@ class HtpSession:
                 ready = max(ready, dep.tick)
         if not txn.requests:          # nothing crosses the wire
             return TransactionResult(done=ready)
+        with spans.span("sess:submit"):
+            result = self._send(txn, ready)
+        if self.trace is not None:
+            self.trace.on_submit(stream, txn, deps, at, ready, result)
+        return result
+
+    def _send(self, txn: HtpTransaction, ready: int) -> TransactionResult:
+        """One non-empty transaction through the synchronous link
+        arithmetic, from tick ``ready``."""
         ch = self.channel
         self.stats.transactions += 1
         start = ch.begin(ready)
@@ -345,8 +352,6 @@ class HtpSession:
             result.done = max(result.ticks)
         else:
             result.done = result.ticks[-1]
-        if self.trace is not None and not self._trace_suspend:
-            self.trace.on_submit(stream, txn, deps, at, ready, result)
         return result
 
     # ------------------------------------------------------------------

@@ -1,0 +1,48 @@
+"""Named host spans on the profiler's clock.
+
+Each layer of the host side marks its work with :func:`span`, a
+``jax.profiler.TraceAnnotation`` named ``fase:<layer>:<what>``.  A span
+is recorded only while a profiler session runs (``jax.profiler.trace``,
+``jax.profiler.start_trace`` or a ``ProfilerSession``), on the host plane
+and the clock of the runtime's program launches and the device's program
+executions.  With no session a span costs one TraceMe construction.
+
+Layer prefixes, so that a reader selects a layer by prefix:
+
+* ``fase:chunk``: one chunk of the target, from its launch to the end of
+  the clock read that waits on it;
+* ``fase:rt:``: the host runtime (``run``, ``load``, ``finish``,
+  ``poll``, ``dispatch``, ``exception``, ``hfutex``, ``syscall``,
+  ``sys:<name>``, ``pagefault``);
+* ``fase:sess:submit``: one transaction through the session and link
+  model;
+* ``fase:sync:<accessor>``: a target accessor that brings a device value
+  to the host; ``fase:acc:<accessor>``: one that only launches a
+  program.
+
+Every call site goes through :func:`span`, so a test may put a recorder
+in its place.
+"""
+from __future__ import annotations
+
+import functools
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "fase:"
+
+
+def span(name: str):
+    """The span ``fase:<name>``, as a context manager."""
+    return TraceAnnotation(PREFIX + name)
+
+
+def traced(name: str):
+    """Decorate a function so that each call runs inside ``span(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap

@@ -141,8 +141,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     # XLA cost_analysis counts while-loop bodies ONCE; the layer stack is a
     # scan (and train adds a microbatch scan), so scale by the static trip
     # counts.  Out-of-loop ops (embeds/logits) are amortised into the
-    # multiplier — the roofline.py useful-FLOP cross-check validates this
-    # against 6*N*D model FLOPs.
+    # multiplier.
     trip_mult = _scan_trip_count(cfg)
     if kind == "train":
         trip_mult *= max(n_micro, 1)

@@ -12,7 +12,7 @@ profiler with the TPU tracer in its light mode (host events only), as
 the cell benchmark records its first unit (``bench/fasebench``).  One
 JSON line holds, per thousand guest instructions (``_ms_per_kinstr``):
 
-  chunk_wait      host wall inside ``fase:chunk`` (launch to clock read)
+  chunk_wait      host wall inside ``fase:chunk`` (launch to record read)
   between_chunks  the cell benchmark's reading of the same job: wall
                   outside the waits on chunks as its launch events infer
                   them (``bench/metrics/between_chunks_ms_per_kinstr.py``)
@@ -22,7 +22,10 @@ JSON line holds, per thousand guest instructions (``_ms_per_kinstr``):
                   every ``fase:chunk``
 
 and ``layer_share`` (the three layers over ``between_chunks``),
-``host_syncs_per_chunk``, the span count by name, both jobs' walls and
+``host_syncs_per_chunk`` (``fase:sync:`` spans, the chunk record's read
+included) beside ``shadow_reads_per_chunk`` (reads ``JaxTarget``
+answered from its state shadow, with no device transfer), the span
+count by name, both jobs' walls and
 the cost of one span with the profiler off and on.  ``--slice`` runs one
 more job with the TPU tracer's full mode over 1 s of it from 1 s in and
 adds the device's idle share and its idle gaps, each labelled by the
@@ -136,8 +139,20 @@ def split(spans, kinstr: float) -> dict:
             "host_syncs_per_chunk": syncs / names[CHUNK]}
 
 
-def reduce_job(data, guest_instr: int) -> dict:
-    """The recorded job (inside its ``JOB`` span) of a light recording."""
+def shadow_split(spans, shadow_reads: int | None) -> dict:
+    """``shadow_reads_per_chunk``: the target's shadow-served reads per
+    ``fase:chunk`` span; empty without a count or a chunk."""
+    chunks = sum(1 for sp in spans if sp[0] == CHUNK)
+    if shadow_reads is None or not chunks:
+        return {}
+    return {"shadow_reads_per_chunk": shadow_reads / chunks}
+
+
+def reduce_job(data, guest_instr: int, shadow_reads: int | None = None
+               ) -> dict:
+    """The recorded job (inside its ``JOB`` span) of a light recording;
+    ``shadow_reads``, the target's count for the job, adds
+    ``shadow_reads_per_chunk``."""
     from fasebench.spec import metric_reader
     (_, t0, t1, _), = program_spans(data, JOB)
     programs, _ = xtrace.host_events(data)
@@ -149,6 +164,7 @@ def reduce_job(data, guest_instr: int) -> dict:
            "chunk_launches": len(unit.chunks()),
            "other_launches": len(unit.others()),
            **split(spans, unit.kinstr),
+           **shadow_split(spans, shadow_reads),
            "between_chunks_ms_per_kinstr": metric_reader(
                ROOT, "between_chunks_ms_per_kinstr")(xtrace.Trace(unit))}
     three = [out[f"{k}_ms_per_kinstr"] for k in (*LAYERS, "accessor_host")]
@@ -232,12 +248,12 @@ def main(argv=None) -> None:
             rt.load(build(args.job), argv_, files=files)
             rep = rt.run(max_ticks=1 << 44)
             jax.block_until_ready(tgt.st)
-        return rep, time.perf_counter() - t
+        return rep, time.perf_counter() - t, tgt.shadow_reads
 
     job()                                    # warm-up: compiles
-    rep, untraced_s = job()
+    rep, untraced_s, _ = job()
     session = _profiler.ProfilerSession(profile_options(full=False))
-    rep2, traced_s = job()
+    rep2, traced_s, shadow_reads = job()
     raw = session.stop()
     if rep2 != rep:
         raise RuntimeError("the traced job's report differs from the "
@@ -249,7 +265,7 @@ def main(argv=None) -> None:
            "span_us_off": span_cost_us(),
            "span_us_on": span_cost_us(profile_options(full=False)),
            **reduce_job(ProfileData.from_serialized_xspace(raw),
-                        sum(rep.instret))}
+                        sum(rep.instret), shadow_reads)}
     if args.slice:
         rec = Recording(full=True)
         with rec.over(xtrace.SLICE_START_S, xtrace.SLICE_S):

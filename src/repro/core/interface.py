@@ -125,6 +125,14 @@ def unpack_read_batch(got, n_regs, n_words, names, order):
             [int(v) for v in wv[:n_words]])
 
 
+#: offset of each per-core field in the chunk record, and every name the
+#: shadow serves (:func:`repro.core.target.cpu.state_record`)
+_FIELD = {name: k for k, name in enumerate(_cpu.SNAPSHOT_CORE_FIELDS)}
+_SHADOWED = frozenset(_FIELD) | {"ticks"}
+_MASK64 = (1 << 64) - 1
+_MASK32 = (1 << 32) - 1
+
+
 class Target(Protocol):
     """Host-visible surface of a FASE-instrumented target processor."""
 
@@ -144,7 +152,7 @@ class Target(Protocol):
     # Reg bundle ----------------------------------------------------------
     def reg_read(self, c: int, idx: int) -> int: ...
     def reg_write(self, c: int, idx: int, v: int) -> None: ...
-    # Batched host reads (one device sync for any mix of reads) ------------
+    # Batched host reads (at most one device sync for any mix) -------------
     def fetch_batch(self, regs=(), csrs=(), words=()) -> tuple: ...
     # Batched host writes (one device update for a staged transaction) -----
     def commit_batch(self, regs=(), csrs=(), words=()) -> None: ...
@@ -173,11 +181,27 @@ class JaxTarget:
     while-loop; host-side accesses use tiny donating micro-ops so nothing is
     ever copied wholesale.
 
+    The host keeps a shadow of the per-core state: the clock and every
+    core's registers, ``pc``, ``priv``, ``pending``, ``stall_until``,
+    ``satp``, trap CSRs, ``res``, ``uticks`` and ``instret``
+    (:func:`repro.core.target.cpu.state_record`).  The program that runs
+    a chunk also returns that record; the first read after the launch
+    brings it home (``fase:sync:chunk_record``, where the host waits on
+    the chunk), and until the next chunk those fields are read from it
+    with no device call.  Every writer here sends its values to the
+    device and into the shadow alike; any other replacement of ``st``
+    drops the shadow and the next read refills it
+    (``fase:sync:shadow_fill``).  At every host instant a value the
+    shadow serves equals what a device read would return.
+    ``shadow_reads`` counts the accessor calls it answered with no
+    device transfer of their own.
+
     ``fast_path`` (default on) selects the batched-issue vectorized
     interpreter with the per-core fetch-block cache
     (:func:`repro.core.target.cpu.run_chunk_fast`); ``fast_path=False``
     falls back to the scalar one-instruction-per-iteration reference
-    loop.  Both are bit-identical to :class:`~repro.core.target.pysim.\
+    loop, after which the shadow refills on the first read.  Both are
+    bit-identical to :class:`~repro.core.target.pysim.\
 PySim` — the knobs trade compile time and host speed, never semantics:
 
       * ``issue_width`` — ticks retired per compiled loop iteration,
@@ -211,7 +235,64 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         self.trace_slots = 0          # commit-trace ring, off by default
         self._trace_base: list = []
         self._trigger: tuple | None = None   # capture-window predicate
+        self.shadow_reads = 0
         self.st = _cpu.make_state(n_cores, mem_bytes)
+
+    # -- the state shadow -------------------------------------------------
+    @property
+    def st(self) -> _cpu.CpuState:
+        return self._st
+
+    @st.setter
+    def st(self, st: _cpu.CpuState) -> None:
+        """Replace the whole state: the shadow is dropped and the next
+        read of per-core state refills it from the device."""
+        self._st = st
+        self._shadow: np.ndarray | None = None
+        self._record: jax.Array | None = None   # last chunk's, unread
+
+    def _shadow_now(self, count: bool = True) -> np.ndarray:
+        """The shadow, current: the last chunk's record brought home on
+        the first access after the chunk, or the state read whole after
+        a drop.  ``count`` adds a read served with no transfer to
+        ``shadow_reads``."""
+        if self._record is not None:
+            with spans.span("sync:chunk_record"):
+                self._shadow = np.array(self._record)
+            self._record = None
+        elif self._shadow is None:
+            with spans.span("sync:shadow_fill"):
+                self._shadow = np.array(_cpu.state_record(self._st))
+        elif count:
+            self.shadow_reads += 1
+        return self._shadow
+
+    def _slot(self, c: int, name: str) -> int:
+        """Index of core ``c``'s field ``name`` (or the clock) in the
+        record."""
+        return 0 if name == "ticks" else 1 + _FIELD[name] * self.nc + c
+
+    def _reg_slot(self, c: int, idx: int) -> int:
+        return 1 + len(_FIELD) * self.nc + c * 32 + idx
+
+    def _put(self, slot: int, v: int) -> None:
+        """Write ``v`` through to ``slot`` of the shadow, the last
+        chunk's record brought home first; nothing while it is
+        dropped."""
+        if self._record is not None:
+            self._shadow_now(count=False)
+        if self._shadow is not None:
+            self._shadow[slot] = v & _MASK64
+
+    def _write_through(self, c: int, name: str, v: int) -> None:
+        """Put the value a device op just wrote into field ``name`` of
+        core ``c`` into the shadow, as the device stores it."""
+        if name == "pending":
+            v = int(v != 0)
+        elif name == "priv":
+            v &= _MASK32
+        if name in _SHADOWED:
+            self._put(self._slot(c, name), v)
 
     # -- inst stream ------------------------------------------------------
     @property
@@ -219,58 +300,71 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         return self.nc
 
     def run(self, max_cycles: int = 1 << 62):
+        """Launch one chunk; on the fast path the same program returns
+        the chunk's state record for the shadow."""
         budget = min(max_cycles, self.chunk_cycles)
         if self.fast_path:
-            self.st = _cpu.run_chunk_fast(
-                self.st, self.nc, self.mem_bytes, budget,
-                self.issue_width, self.block_words, self.block_cache,
-                self.fetch_kernel, self.trace_slots > 0,
+            self._st, self._record = _cpu.run_chunk_fast_record(
+                self._st, _cpu.run_chunk_fast, self.nc, self.mem_bytes,
+                budget, self.issue_width, self.block_words,
+                self.block_cache, self.fetch_kernel, self.trace_slots > 0,
                 self._trigger if self.trace_slots > 0 else None,
                 self.dtlb_ways)
+            self._shadow = None
         else:
-            self.st = _cpu.run_chunk(self.st, self.nc, self.mem_bytes,
+            self.st = _cpu.run_chunk(self._st, self.nc, self.mem_bytes,
                                      budget)
 
     @spans.traced("acc:redirect")
     def redirect(self, c, pc, resume_tick=0):
         # one donated jitted dispatch, not four eager scatters
-        self.st = _cpu.redirect_op(self.st, np.int32(c), np.uint64(pc),
-                                   np.uint64(max(resume_tick, 0)))
+        pc, resume = int(pc), max(resume_tick, 0)
+        self._st = _cpu.redirect_op(self._st, np.int32(c), np.uint64(pc),
+                                    np.uint64(resume))
+        for name, v in (("pc", pc), ("priv", 0), ("pending", 0),
+                        ("stall_until", resume)):
+            self._write_through(c, name, v)
 
     @spans.traced("acc:park")
     def park(self, c):
-        self.st = _cpu.park_op(self.st, np.int32(c))
+        self._st = _cpu.park_op(self._st, np.int32(c))
+        self._write_through(c, "priv", 3)
+        self._write_through(c, "pending", 0)
 
-    @spans.traced("sync:pending_cores")
     def pending_cores(self):
-        return list(np.nonzero(np.asarray(self.st.pending))[0])
+        base = self._slot(0, "pending")
+        return np.flatnonzero(
+            self._shadow_now()[base:base + self.nc]).tolist()
 
     @spans.traced("acc:clear_pending")
     def clear_pending(self, c):
-        self.st = _cpu.clear_pending_op(self.st, np.int32(c))
+        self._st = _cpu.clear_pending_op(self._st, np.int32(c))
+        self._write_through(c, "pending", 0)
 
     # -- priv / csr ---------------------------------------------------------
     def csr_read(self, c, name):
-        # the 1-element batched gather: a jitted dispatch is several
-        # times cheaper than an eager un-jitted __getitem__
+        if name in _SHADOWED:
+            return int(self._shadow_now()[self._slot(c, name)])
         return self.fetch_batch(csrs=[(c, name)])[1][0]
 
-    @spans.traced("sync:get_priv")
     def get_priv(self, c):
-        return int(np.asarray(self.st.priv[c]))
+        return self.csr_read(c, "priv")
 
     @spans.traced("acc:csr_write")
     def csr_write(self, c, name, v):
         """Host-side CSR/core-state write (CsrW's device half; snapshot
         restore).  Each field keeps its device dtype; ``ticks`` is the
         global clock scalar.  One jitted donated dispatch per write."""
-        self.st = _cpu.csr_write_op(self.st, name, np.int32(c),
-                                    np.uint64(v & ((1 << 64) - 1)))
+        v = int(v) & _MASK64
+        self._st = _cpu.csr_write_op(self._st, name, np.int32(c),
+                                     np.uint64(v))
+        self._write_through(c, name, v)
 
     @spans.traced("acc:set_satp")
     def set_satp(self, c, v):
-        self.st = _cpu.csr_write_op(self.st, "satp", np.int32(c),
-                                    np.uint64(v))
+        self._st = _cpu.csr_write_op(self._st, "satp", np.int32(c),
+                                     np.uint64(v))
+        self._write_through(c, "satp", int(v))
 
     def sfence(self, c):
         # nothing cached across chunks: the slow path walks every access
@@ -282,36 +376,45 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
     # -- regs -----------------------------------------------------------------
     def reg_read(self, c, idx):
-        return self.fetch_batch(regs=[(c, idx)])[0][0]
+        return int(self._shadow_now()[self._reg_slot(c, idx)])
 
-    @spans.traced("sync:fetch_batch")
     def fetch_batch(self, regs=(), csrs=(), words=()):
-        """Batched host reads: ONE blocking device sync for any mix of
-        GPRs (``(core, idx)`` pairs), CSR/core-state fields
-        (``(core, name)`` pairs) and physical words (byte addresses).
-        Returns three int lists in input order, bit-identical to the
-        per-element accessors — this is the device half of the session
-        layer's read batching (ROADMAP item 1): a RegR×31 context save
-        is one transfer, not 31 round trips.  Index arrays are
-        pow2-padded into one jitted gather
-        (:func:`repro.core.target.cpu.fetch_read_batch`), so a handful
-        of compiled shapes serve every request mix — per-element eager
-        gathers would pay one dispatch each and one compile per size."""
-        regs, words = list(regs), list(words)
-        packed = pack_read_batch(regs, csrs, words)
-        if packed is None:
-            return [], [], []
-        names, reg_cpu, reg_idx, word_idx, csr_cpus, order = packed
-        got = jax.device_get(_cpu.fetch_read_batch(
-            self.st, names, reg_cpu, reg_idx, word_idx, csr_cpus))
-        return unpack_read_batch(got, len(regs), len(words), names, order)
+        """Batched host reads of any mix of GPRs (``(core, idx)``
+        pairs), CSR/core-state fields (``(core, name)`` pairs) and
+        physical words (byte addresses).  Returns three int lists in
+        input order, bit-identical to the per-element accessors.  GPRs
+        and the fields the shadow holds come from it; words and the
+        other fields (telemetry counters, trace state) from ONE blocking
+        device gather (``fase:sync:fetch_batch``,
+        :func:`repro.core.target.cpu.fetch_read_batch`), its index
+        arrays pow2-padded so that a handful of compiled shapes serve
+        every request mix."""
+        regs, csrs, words = list(regs), list(csrs), list(words)
+        far = [(c, n) for c, n in csrs if n not in _SHADOWED]
+        sh = None
+        if regs or len(far) < len(csrs):
+            sh = self._shadow_now(count=not (far or words))
+        far_vals, wv = [], []
+        if far or words:
+            with spans.span("sync:fetch_batch"):
+                names, reg_cpu, reg_idx, word_idx, csr_cpus, order = \
+                    pack_read_batch((), far, words)
+                got = jax.device_get(_cpu.fetch_read_batch(
+                    self._st, names, reg_cpu, reg_idx, word_idx, csr_cpus))
+            _, far_vals, wv = unpack_read_batch(got, 0, len(words), names,
+                                                order)
+        far_it = iter(far_vals)
+        cv = [int(sh[self._slot(c, n)]) if n in _SHADOWED else next(far_it)
+              for c, n in csrs]
+        return [int(sh[self._reg_slot(c, i)]) for c, i in regs], cv, wv
 
     @spans.traced("acc:reg_write")
     def reg_write(self, c, idx, v):
         if idx != 0:
-            self.st = _cpu.reg_write_op(self.st, np.int32(c),
-                                        np.int32(idx),
-                                        np.uint64(v & ((1 << 64) - 1)))
+            v = int(v) & _MASK64
+            self._st = _cpu.reg_write_op(self._st, np.int32(c),
+                                         np.int32(idx), np.uint64(v))
+            self._put(self._reg_slot(c, idx), v)
 
     @spans.traced("acc:commit_batch")
     def commit_batch(self, regs=(), csrs=(), words=()):
@@ -325,11 +428,18 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         64-bit-masked, and ``x0``/``ticks`` never appear; arrays are
         pow2-padded with out-of-bounds drop sentinels so a handful of
         shapes serve every transaction.  Bit-identical to replaying the
-        per-element accessors in order."""
+        per-element accessors in order; registers and fields go into the
+        shadow too."""
+        regs, csrs = list(regs), list(csrs)
         packed = pack_write_batch(self.nc, self.mem_bytes >> 3,
                                   regs, csrs, words)
-        if packed is not None:
-            self.st = _cpu.apply_write_batch(self.st, *packed)
+        if packed is None:
+            return
+        self._st = _cpu.apply_write_batch(self._st, *packed)
+        for c, idx, v in regs:
+            self._put(self._reg_slot(c, idx), int(v))
+        for c, name, v in csrs:
+            self._write_through(c, name, int(v))
 
     # -- memory ---------------------------------------------------------------
     def mem_read_word(self, pa):
@@ -337,46 +447,43 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
     @spans.traced("acc:mem_write_word")
     def mem_write_word(self, pa, v):
-        self.st = self.st._replace(
-            mem=_cpu.mem_write_words(self.st.mem,
+        self._st = self._st._replace(
+            mem=_cpu.mem_write_words(self._st.mem,
                                      jnp.asarray([pa >> 3]),
                                      jnp.asarray([v], dtype=jnp.uint64)))
 
     @spans.traced("sync:page_read")
     def page_read(self, ppn):
-        return np.asarray(_cpu.page_read_words(self.st.mem,
+        return np.asarray(_cpu.page_read_words(self._st.mem,
                                                (ppn << 12) >> 3))
 
     @spans.traced("acc:page_write")
     def page_write(self, ppn, words):
         w = jnp.asarray(np.ascontiguousarray(words, dtype=np.uint64))
-        self.st = self.st._replace(
-            mem=_cpu.page_write_words(self.st.mem, (ppn << 12) >> 3, w))
+        self._st = self._st._replace(
+            mem=_cpu.page_write_words(self._st.mem, (ppn << 12) >> 3, w))
 
     @spans.traced("acc:page_set")
     def page_set(self, ppn, val):
-        self.st = self.st._replace(
-            mem=_cpu.page_set_words(self.st.mem, (ppn << 12) >> 3,
+        self._st = self._st._replace(
+            mem=_cpu.page_set_words(self._st.mem, (ppn << 12) >> 3,
                                     np.uint64(val)))
 
     @spans.traced("acc:page_copy")
     def page_copy(self, src_ppn, dst_ppn):
-        self.st = self.st._replace(
-            mem=_cpu.page_copy_words(self.st.mem, (src_ppn << 12) >> 3,
+        self._st = self._st._replace(
+            mem=_cpu.page_copy_words(self._st.mem, (src_ppn << 12) >> 3,
                                      (dst_ppn << 12) >> 3))
 
     # -- perf --------------------------------------------------------------
-    @spans.traced("sync:get_ticks")
     def get_ticks(self):
-        return int(np.asarray(self.st.ticks))
+        return self.csr_read(0, "ticks")
 
-    @spans.traced("sync:get_uticks")
     def get_uticks(self, c):
-        return int(np.asarray(self.st.uticks[c]))
+        return self.csr_read(c, "uticks")
 
-    @spans.traced("sync:get_instret")
     def get_instret(self, c):
-        return int(np.asarray(self.st.instret[c]))
+        return self.csr_read(c, "instret")
 
     # -- telemetry: commit-trace ring (repro.telemetry) --------------------
     @spans.traced("acc:trace_arm")
@@ -388,6 +495,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
             "commit-trace capture needs the fast path (run_chunk_fast)"
         assert slots > 0
         self.trace_slots = slots
+        # a replacement of the state: the shadow refills on the next read
         self.st = self.st._replace(
             tracebuf=jnp.zeros((self.nc, slots, 4), jnp.uint64),
             trace_n=jnp.zeros((self.nc,), jnp.uint64),
@@ -403,7 +511,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         compiles into the trace path and ``None`` compiles it out
         entirely.  Arm/disarm state rewinds to disarmed."""
         self._trigger = spec
-        self.st = self.st._replace(
+        self._st = self._st._replace(
             trace_armed=jnp.zeros((self.nc,), jnp.bool_))
 
     @spans.traced("sync:trace_drain")

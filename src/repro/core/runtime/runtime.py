@@ -323,7 +323,7 @@ class FaseRuntime:
                 if c not in self.sched.running]
         if not idle:
             return
-        # one batched device fetch for every idle core's privilege level
+        # one batched read of every idle core's privilege level
         # (switch_in only redirects the core it dispatches, so the other
         # cores' priv values stay valid across the loop)
         _, privs, _ = self.target.fetch_batch(
@@ -348,7 +348,7 @@ class FaseRuntime:
             self.target.park(cpu)
             return
         # controller-internal peek for the HFutex fast path (§V-B):
-        # both CSRs in one batched device sync, not two round trips
+        # both CSRs in one batched read, not two
         _, (cause, epc), _ = self.target.fetch_batch(
             csrs=[(cpu, "mcause"), (cpu, "mepc")])
         with spans.span("rt:hfutex"):

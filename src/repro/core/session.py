@@ -361,10 +361,12 @@ class HtpSession:
     _NEXT_READS = ("mcause", "mepc", "mtval")
 
     def _prefetch_reads(self, txn: HtpTransaction):
-        """Gather every register/CSR/word read of ``txn`` into ONE device
-        fetch (``Target.fetch_batch``) instead of one blocking round trip
-        per element — the first step of ROADMAP item 1 (a RegR×31 context
-        save is one transfer, not 31).  Values are bit-identical to the
+        """Gather every register/CSR/word read of ``txn`` into ONE
+        ``Target.fetch_batch`` call instead of one call per element (a
+        RegR×31 context save is one call, not 31); on ``JaxTarget`` the
+        registers and core-state fields come from its state shadow and
+        only memory words, or fields the shadow lacks, make a device
+        round trip.  Values are bit-identical to the
         per-element accessors; a read whose location an *earlier* request
         of the same transaction writes is excluded and falls back to a
         direct read at apply time.  Returns a dict keyed by request

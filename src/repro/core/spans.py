@@ -10,15 +10,18 @@ executions.  With no session a span costs one TraceMe construction.
 Layer prefixes, so that a reader selects a layer by prefix:
 
 * ``fase:chunk``: one chunk of the target, from its launch to the end of
-  the clock read that waits on it;
+  the first read after it (on ``JaxTarget``, ``fase:sync:chunk_record``,
+  the read of the chunk's state record, which waits on the chunk);
 * ``fase:rt:``: the host runtime (``run``, ``load``, ``finish``,
   ``poll``, ``dispatch``, ``exception``, ``hfutex``, ``syscall``,
   ``sys:<name>``, ``pagefault``);
 * ``fase:sess:submit``: one transaction through the session and link
   model;
-* ``fase:sync:<accessor>``: a target accessor that brings a device value
-  to the host; ``fase:acc:<accessor>``: one that only launches a
-  program.
+* ``fase:sync:<what>``: a target accessor that brings a device value to
+  the host (``chunk_record``, ``shadow_fill``, ``fetch_batch``,
+  ``page_read``, ``trace_drain``); a read ``JaxTarget`` answers from its
+  state shadow has none; ``fase:acc:<accessor>``: one that only
+  launches a program.
 
 Every call site goes through :func:`span`, so a test may put a recorder
 in its place.

@@ -1170,6 +1170,40 @@ run_chunk_fast = partial(jax.jit,
                          donate_argnums=(0,))(_run_chunk_fast)
 
 
+@jax.jit
+def state_record(st: CpuState) -> jax.Array:
+    """The per-core state the host reads between chunks, as one u64
+    vector: ``ticks``, then each :data:`SNAPSHOT_CORE_FIELDS` field over
+    the cores (``[1 + k * nc + c]``), then every core's 32 registers
+    (``[1 + 11 * nc + c * 32 + idx]``).  Each value is widened to u64
+    as :func:`fetch_read_batch` widens it."""
+    return jnp.concatenate(
+        [st.ticks[None].astype(U64)] +
+        [getattr(st, f).astype(U64) for f in SNAPSHOT_CORE_FIELDS] +
+        [st.regs.reshape(-1)])
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 5, 6, 7, 8, 9, 10, 11),
+         donate_argnums=(0,))
+def run_chunk_fast_record(st: CpuState, kernel, n_cores: int,
+                          mem_bytes: int, max_cycles, issue_width: int,
+                          block_words: int, block_cache: bool,
+                          fetch_kernel: str, trace_on: bool,
+                          trigger: tuple | None, dtlb_ways: int):
+    """One chunk and its :func:`state_record`, in one program: the host
+    brings the record home with the chunk's end and answers its reads of
+    per-core state from it until the next chunk.
+
+    ``kernel`` is :func:`run_chunk_fast` or a function with its
+    signature, given by the caller at each launch (static), so that
+    whatever this module's ``run_chunk_fast`` names when a chunk is
+    launched, a wrapped kernel included, is what runs."""
+    st = kernel(st, n_cores, mem_bytes, max_cycles, issue_width,
+                block_words, block_cache, fetch_kernel, trace_on, trigger,
+                dtlb_ways)
+    return st, state_record(st)
+
+
 @partial(jax.jit, static_argnums=(1, 2, 4, 5, 6, 7, 8, 9),
          donate_argnums=(0,))
 def run_chunk_fleet(sts: CpuState, n_cores: int, mem_bytes: int, budgets,

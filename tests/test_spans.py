@@ -101,13 +101,29 @@ def test_span_counts_match_counters(recorded, plain_report):
         names["fase:rt:finish"] == 1
     assert names["fase:rt:poll"] == names["fase:rt:dispatch"] == \
         calls["run"]
-    # every chunk's wait ends in one clock read, and the loop reads the
-    # clock once more before each chunk
-    assert names["fase:sync:get_ticks"] >= 2 * calls["run"]
+    # every chunk's wait ends in one read of its record, and the reads
+    # of per-core state between chunks come from the target's shadow
+    assert names["fase:sync:chunk_record"] == calls["run"]
     assert names["fase:sync:fetch_batch"] > 0
     assert names["fase:acc:commit_batch"] > 0
     assert all(n.split(":")[1] in ("chunk", "rt", "sess", "sync", "acc")
                for n in names)
+
+
+def test_shadow_serves_the_per_core_reads(recorded):
+    """Of the job's 1 228 blocking reads without a shadow (10.233 a
+    chunk), the chunks' records, one fill before the first chunk and the
+    reads of memory words reach the device; the rest are served by the
+    shadow."""
+    names, calls, rt, _ = recorded
+    syncs = {n: k for n, k in names.items() if n.startswith("fase:sync:")}
+    assert syncs == {"fase:sync:chunk_record": 120,
+                     "fase:sync:shadow_fill": 1,
+                     "fase:sync:fetch_batch": 55}
+    assert rt.target.shadow_reads == 1052
+    assert sum(syncs.values()) + rt.target.shadow_reads == 1228
+    assert sum(syncs.values()) / names["fase:chunk"] == \
+        pytest.approx(1.4666666666666666)
 
 
 def test_spans_leave_results_alone(plain_report):
@@ -147,12 +163,13 @@ def test_self_time_subtracts_nested_spans():
     spans_ = [sp("rt:run", 0, 100), sp("rt:exception", 10, 60),
               sp("rt:syscall", 20, 50), sp("sess:submit", 25, 45),
               sp("sync:fetch_batch", 30, 40), sp("chunk", 70, 90),
-              sp("sync:get_ticks", 85, 90)]
+              sp("sync:chunk_record", 85, 90)]
     # run 100 - (exception 50 + chunk 20); exception 50 - 30; syscall
     # 30 - 20
     assert self_ns(spans_, "fase:rt:") == 30 + 20 + 10
     assert self_ns(spans_, "fase:sess:") == 20 - 10
-    # the clock read inside the chunk is the wait on it, not accessor time
+    # the record read inside the chunk is the wait on it, not accessor
+    # time
     assert outside_chunks_ns(spans_, ("fase:acc:", "fase:sync:")) == 10
     out = split(spans_, kinstr=0.5)
     assert out == pytest.approx({
@@ -168,7 +185,7 @@ def test_self_time_subtracts_nested_spans():
 
 def test_spans_on_two_threads_nest_apart():
     spans_ = [sp("rt:run", 0, 100, "a"), sp("sync:fetch_batch", 10, 20, "b"),
-              sp("chunk", 0, 50, "b"), sp("sync:get_ticks", 40, 50, "b"),
+              sp("chunk", 0, 50, "b"), sp("sync:chunk_record", 40, 50, "b"),
               sp("acc:redirect", 30, 40, "a")]
     # a's accessor is not inside b's chunk; b's fetch is
     assert outside_chunks_ns(spans_, ("fase:acc:", "fase:sync:")) == 10
@@ -193,10 +210,10 @@ def test_idle_gaps_take_the_innermost_program_span():
         launches=[(5, "_run_chunk_fast"), (29, "fetch_read_batch"),
                   (49, "_run_chunk_fast")],
         spans=[("unit", 0, 100), sp("rt:run", 0, 100), sp("chunk", 4, 12),
-               sp("sync:get_ticks", 8, 12), sp("rt:exception", 14, 40),
+               sp("sync:chunk_record", 8, 12), sp("rt:exception", 14, 40),
                sp("sync:fetch_batch", 29, 33)])
     assert dict(sl.idle_gaps()) == pytest.approx({
-        "fase:sync:get_ticks before fetch_read_batch": 20e-9,
+        "fase:sync:chunk_record before fetch_read_batch": 20e-9,
         "fase:sync:fetch_batch before _run_chunk_fast": 19e-9})
 
 
@@ -214,15 +231,15 @@ def test_recorded_chip_trace(recorded):
     out = reduce_job(data, sum(rep.instret))
     assert out.pop("spans") == dict(sorted(names.items()))
     assert out["chunk_launches"] == calls["run"] == 120
-    assert out["host_syncs_per_chunk"] == pytest.approx(1228 / 120)
+    assert out["host_syncs_per_chunk"] == pytest.approx(176 / 120)
     assert out == pytest.approx({
-        "wall_s": 2.921366343, "kinstr": 38.025, "chunk_launches": 120,
-        "other_launches": 1274,
-        "chunk_wait_ms_per_kinstr": 24.86808391847469,
-        "runtime_self_ms_per_kinstr": 0.6840953583168968,
-        "session_self_ms_per_kinstr": 1.2893135568704799,
-        "accessor_host_ms_per_kinstr": 49.63986558842867,
-        "host_syncs_per_chunk": 10.233333333333333,
-        "between_chunks_ms_per_kinstr": 52.67212071005917,
-        "layer_share": 0.9798974069741432})
+        "wall_s": 1.417661344, "kinstr": 38.025, "chunk_launches": 120,
+        "other_launches": 456,
+        "chunk_wait_ms_per_kinstr": 25.38457412228797,
+        "runtime_self_ms_per_kinstr": 0.6405068244575937,
+        "session_self_ms_per_kinstr": 1.1056689546351084,
+        "accessor_host_ms_per_kinstr": 9.80444996712689,
+        "host_syncs_per_chunk": 1.4666666666666666,
+        "between_chunks_ms_per_kinstr": 12.745715923734386,
+        "layer_share": 0.9062359317698773})
     assert 0.9 <= out["layer_share"] <= 1.1

@@ -74,6 +74,16 @@ def test_run_chunk_fast_compiles_for_v5e(one_chip):
     _fits_one_chip(compiled)
 
 
+def test_run_chunk_fast_record_compiles_for_v5e(one_chip):
+    """The program ``JaxTarget.run`` launches: a chunk and its record."""
+    budget = jax.ShapeDtypeStruct((), jnp.uint64, sharding=one_chip)
+    compiled = cpu.run_chunk_fast_record.lower(
+        _state(one_chip), cpu.run_chunk_fast, NC, MEM, budget,
+        KW["issue_width"], KW["block_words"], KW["block_cache"],
+        KW["fetch_kernel"], False, None, KW["dtlb_ways"]).compile()
+    _fits_one_chip(compiled)
+
+
 def test_run_chunk_fleet_compiles_for_v5e(one_chip):
     budgets = jax.ShapeDtypeStruct((BOARDS,), jnp.uint64,
                                    sharding=one_chip)
@@ -116,8 +126,13 @@ def _redirect(one_chip):
                                np.uint64(0))))
 
 
-@pytest.mark.parametrize("lower", [_write_batch, _read_batch, _redirect],
+def _state_record(one_chip):
+    return cpu.state_record.lower(_state(one_chip))
+
+
+@pytest.mark.parametrize("lower", [_write_batch, _read_batch, _redirect,
+                                   _state_record],
                          ids=["apply_write_batch", "fetch_read_batch",
-                              "redirect_op"])
+                              "redirect_op", "state_record"])
 def test_host_micro_op_compiles_for_v5e(one_chip, lower):
     _fits_one_chip(lower(one_chip).compile())

@@ -13,14 +13,20 @@ A traffic mix is data (``bench/traffic/<name>.json``):
     answers    keys of ``key value`` lines whose value
                ``bench/answers/<key>.py`` works out from the job's inputs
     max_jobs   the most units a window may run
+    set_seed   (optional) the seed that draws every unit's inputs in place
+               of ``--seed``: every seed gives a unit the same jobs, and
+               so the same work, where a unit lasts as long as its
+               slowest board
 
 Every input file of a job is drawn from ``(--seed, phase, unit, board,
 file)``, so the same seed gives the same inputs and every seed gives
 the same sizes.
 
 A unit is one whole job on a solo board (``FaseRuntime.load`` then
-``run``, ``bench/units/solo.py``).  Units call the program's own entry
-points and re-implement neither.
+``run``, ``bench/units/solo.py``), or one lockstep round of a whole job
+on each board of a fleet (``FleetRuntime.run_synchronous``,
+``bench/units/fleet.py``).  Units call the program's own entry points
+and re-implement neither.
 """
 from __future__ import annotations
 
@@ -85,6 +91,14 @@ def job_input(traffic: dict, seed: int, phase: int, unit: int,
                     tuple(files), tuple(seeds))
 
 
+def unit_inputs(traffic: dict, seed: int, phase: int, unit: int,
+                boards: int) -> list[JobInput]:
+    """The board-jobs of one unit, in board order."""
+    seed = traffic.get("set_seed", seed)
+    return [job_input(traffic, seed, phase, unit, b)
+            for b in range(boards)]
+
+
 #: words a single batched read may carry: a guest page (512 words) and
 #: every smaller power of two, which page-sized and smaller reads of
 #: some jobs use and others do not
@@ -94,20 +108,26 @@ READ_WORDS = tuple(1 << k for k in range(10))
 class Units:
     """How a cell runs its units: a file ``bench/units/<name>.py``, named
     by the configuration's ``units``, defines a subclass ``Units`` with
-    ``boards``, ``run(jobs, span)`` (one unit, returning one report per
-    board) and ``read_target()`` (a target for the warm-up's reads)."""
+    ``boards``, ``unit(jobs, span)`` (one unit: one report per board, and
+    a target of the unit's own to read from) and ``chunk_kernel``, the
+    name of the function of ``repro.core.target.cpu`` that the unit
+    launches every chunk through, looked up at each launch (the
+    benchmark's tests break the timed path there)."""
 
     boards = 1
 
     def __init__(self, cfg: dict):
         self.cfg = cfg
 
+    def run(self, jobs: list[JobInput], span) -> list:
+        """One unit's reports, one a board, in board order."""
+        return self.unit(jobs, span)[0]
+
     def warm_up(self, jobs: list[JobInput], span) -> None:
-        """One whole unit, then a batched read of each size in
-        ``READ_WORDS``: together they compile every program the window
-        runs, whatever its jobs' inputs."""
-        self.run(jobs, span)
-        target = self.read_target()
+        """One whole unit, then on its own target a batched read of each
+        size in ``READ_WORDS``: together they compile every program the
+        window runs, whatever its jobs' inputs."""
+        _, target = self.unit(jobs, span)
         for n in READ_WORDS:
             target.fetch_batch(words=[0] * n)
 

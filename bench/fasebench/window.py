@@ -15,7 +15,8 @@
              after peak memory has been read (``fasebench.check``).
 
 A unit that has not ended ``grace_s`` seconds after the window's close
-is stopped and its board-jobs count as unfinished.
+is stopped, and its board-jobs count as unfinished, as do those of a
+unit that returns another number of reports than it has boards.
 """
 from __future__ import annotations
 
@@ -174,10 +175,9 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
     traffic = {**cell.traffic, **(traffic_over or {})}
     units = (units_cls or units_class(root, cell.config))(cfg)
     boards = units.boards
-    warm = [jobmod.job_input(traffic, seed, jobmod.WARMUP, 0, b)
-            for b in range(boards)]
-    plan = [[jobmod.job_input(traffic, seed, jobmod.WINDOW, u, b)
-             for b in range(boards)] for u in range(traffic["max_jobs"])]
+    warm = jobmod.unit_inputs(traffic, seed, jobmod.WARMUP, 0, boards)
+    plan = [jobmod.unit_inputs(traffic, seed, jobmod.WINDOW, u, boards)
+            for u in range(traffic["max_jobs"])]
     log(f"inputs: workload {traffic['workload']} argv {traffic['argv']}"
         f" files {traffic['files']} boards {boards}; window file seeds"
         f" {[[j.seeds for j in unit] for unit in plan[:4]]} ...")
@@ -220,7 +220,11 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
         try:
             with watchdog(deadline), recording, _span("unit"):
                 reps = units.run(unit, _span)
-            done.append((u, reps))
+            if len(reps) != boards:
+                tally.unfinished(boards)
+                log(f"unit {u}: {len(reps)} reports for {boards} boards")
+            else:
+                done.append((u, reps))
             if u == 0:
                 traced_instr = sum(sum(r.instret) for r in reps)
         except UnitTimeout:

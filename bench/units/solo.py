@@ -4,6 +4,8 @@ from fasebench.jobs import MAX_TICKS, Units as _Units
 
 
 class Units(_Units):
+    chunk_kernel = "run_chunk_fast"
+
     def __init__(self, cfg: dict):
         from repro.configs.fase_rocket import runtime_kwargs, \
             target_kwargs
@@ -11,17 +13,14 @@ class Units(_Units):
         self.rkw = runtime_kwargs(cfg)
         self.tkw = target_kwargs(cfg)
 
-    def read_target(self):
-        from repro.core.interface import JaxTarget
-        return JaxTarget(self.cfg["n_cores"], self.cfg["mem_bytes"],
-                         **self.tkw)
-
-    def run(self, jobs, span) -> list:
+    def unit(self, jobs, span):
         import jax
+        from repro.core.interface import JaxTarget
         from repro.core.runtime import FaseRuntime
         from repro.core.workloads import build
         (job,) = jobs
-        tgt = self.read_target()
+        tgt = JaxTarget(self.cfg["n_cores"], self.cfg["mem_bytes"],
+                        **self.tkw)
         rt = FaseRuntime(tgt, mode="fase", **self.rkw)
         with span("load"):
             rt.load(build(job.name), [job.name, *job.argv],
@@ -29,4 +28,4 @@ class Units(_Units):
         with span("run"):
             rep = rt.run(max_ticks=MAX_TICKS)
             jax.block_until_ready(tgt.st)
-        return [rep]
+        return [rep], tgt

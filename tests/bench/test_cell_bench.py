@@ -57,7 +57,8 @@ def no_persistent_cache(monkeypatch, tmp_path):
 def test_cell_files_resolve(cell):
     c = load_cell(ROOT, cell)
     cfg = jobmod.target_config(c.config, c.traffic)
-    assert cfg["n_cores"] == 4 and cfg["mem_bytes"] == 1 << 26
+    assert cfg["n_cores"] == 4
+    assert cfg["mem_bytes"] == c.config["deployment"]["mem_bytes"]
     assert cfg["link"] == c.traffic["link"]["link"]
     assert issubclass(units_class(ROOT, c.config), jobmod.Units)
     assert all(callable(answer(ROOT, k))
@@ -79,6 +80,23 @@ def test_job_inputs_follow_the_seed():
     # every seed draws the same sizes: the graph header's vertex count
     assert {f[0][1][:8] for f in (a.files, c.files, w.files)} == \
         {(32).to_bytes(8, "little")}
+
+
+def test_set_seed_fixes_every_units_jobs():
+    """With ``set_seed`` every seed gives a unit the same four graphs, one
+    a board; units and the warm-up still differ."""
+    traffic = load_cell(ROOT, "rocket4-fleet.bc-pcie").traffic
+    traffic = {**traffic, "files": SMALL_GRAPH}
+    units = [jobmod.unit_inputs(traffic, s, jobmod.WINDOW, 0, 4)
+             for s in (2**33 + 1, 2**33 + 2, 2**33 + 3)]
+    assert units[0] == units[1] == units[2]
+    assert len(set(units[0])) == 4
+    later = jobmod.unit_inputs(traffic, 2**33 + 1, jobmod.WINDOW, 1, 4)
+    warm = jobmod.unit_inputs(traffic, 2**33 + 1, jobmod.WARMUP, 0, 4)
+    assert not set(later) & set(units[0]) and not set(warm) & set(units[0])
+    free = {k: v for k, v in traffic.items() if k != "set_seed"}
+    assert jobmod.unit_inputs(free, 2**33 + 1, jobmod.WINDOW, 0, 4) != \
+        jobmod.unit_inputs(free, 2**33 + 2, jobmod.WINDOW, 0, 4)
 
 
 @pytest.mark.parametrize("scale,seed,argv,want", [

@@ -1,6 +1,10 @@
 """The comparison that decides ``correct`` has teeth: a run with the timed
 path broken underneath, or with the control in the program's place,
-comes out not correct.  CPU runs at a smaller image and graph."""
+comes out not correct.  CPU runs at a smaller image and graph.
+
+A chunk fault is planted at the cell's own chunk entry, the function of
+``repro.core.target.cpu`` that its units name as ``chunk_kernel``
+(``run_chunk_fast`` on a solo board, ``run_chunk_fleet`` on a fleet)."""
 from __future__ import annotations
 
 import time
@@ -10,17 +14,23 @@ import pytest
 from benchcells import CELLS, ROOT, SMALL_CONFIG, small_traffic
 
 from fasebench.control import ControlUnits  # noqa: E402
+from fasebench.spec import load_cell, units_class  # noqa: E402
 from fasebench.window import run_cell  # noqa: E402
+from repro.core.fleet import FleetRuntime  # noqa: E402
 from repro.core.runtime import io  # noqa: E402
 from repro.core.target import cpu  # noqa: E402
 
 
-def cpu_run(cell, seed=2**31 + 11, **kw):
+def cpu_run(cell, seed=2**31 + 11, grace_s=6.0, **kw):
     """A run at an 8 MiB image and kron-5 graphs."""
     return run_cell(ROOT, cell, seed, 0.1, False, time.perf_counter(),
-                    require_accelerator=False, grace_s=6.0,
+                    require_accelerator=False, grace_s=grace_s,
                     config_over=SMALL_CONFIG,
                     traffic_over=small_traffic(cell), **kw)
+
+
+def units_of(cell):
+    return units_class(ROOT, load_cell(ROOT, cell).config)
 
 
 @pytest.fixture(autouse=True)
@@ -47,6 +57,25 @@ def altered(kernel):
     return run
 
 
+def half_batch(kernel):
+    """Half of the batch of boards left out: every chunk runs only the
+    first half of the boards, whatever cycles the others were given."""
+    def run(sts, n_cores, mem_bytes, budgets, *args, **kw):
+        budgets = np.array(budgets)
+        budgets[len(budgets) // 2:] = 0
+        return kernel(sts, n_cores, mem_bytes, budgets, *args, **kw)
+    return run
+
+
+def half_results(run_synchronous):
+    """Half of the batch of boards left out of the results: the fleet's
+    lockstep loop runs every job and returns only the first half of its
+    results."""
+    def run(self, jobs, *args, **kw):
+        return run_synchronous(self, jobs, *args, **kw)[:len(jobs) // 2]
+    return run
+
+
 def misread(read):
     """The host runtime's file read hands the guest a graph whose first
     edge points one vertex further: a fault in code that the timed path
@@ -63,20 +92,38 @@ def misread(read):
     return run
 
 
+FLEET_ONLY = (half_batch, half_results)
 FAULTS = [(cell, fault) for cell in CELLS
-          for fault in (unchanged, altered, misread)
-          if fault is not misread or "bc" in cell]
+          for fault in (unchanged, altered, half_batch, half_results,
+                        misread)
+          if (fault is not misread or "bc" in cell)
+          and (fault not in FLEET_ONLY or units_of(cell).boards > 1)]
 EXPECT = {unchanged: {"unfinished"}, altered: {"instret"},
+          half_batch: {"unfinished"}, half_results: {"unfinished"},
           misread: {"bc_delta0"}}
+#: seconds a unit may run past the window's close: a fault that stalls
+#: the guest must run it out, one that lets the job end must never
+#: reach it (it bounds the run, which ends with the job)
+GRACE = {unchanged: 6.0, half_batch: 6.0, altered: 120.0,
+         half_results: 120.0, misread: 120.0}
+
+
+def patch_point(cell, fault):
+    """The object and attribute the fault is planted at."""
+    if fault is misread:
+        return io.FdTable, "read"
+    if fault is half_results:
+        return FleetRuntime, "run_synchronous"
+    return cpu, units_of(cell).chunk_kernel
 
 
 @pytest.mark.parametrize("cell,fault", FAULTS,
                          ids=[f"{c}-{f.__name__}" for c, f in FAULTS])
 def test_broken_timed_path_is_not_correct(cell, fault, monkeypatch):
-    where, name = (io.FdTable, "read") if fault is misread \
-        else (cpu, "run_chunk_fast")
-    out = cpu_run(cell, before_window=lambda: monkeypatch.setattr(
-        where, name, fault(getattr(where, name))))
+    where, name = patch_point(cell, fault)
+    out = cpu_run(cell, grace_s=GRACE[fault],
+                  before_window=lambda: monkeypatch.setattr(
+                      where, name, fault(getattr(where, name))))
     assert out["correct"] is False
     assert out["failed"] >= 1 and out["attempted"] >= 1
     bad = {k for k, c in out["checks"].items() if c["value"]}

@@ -1,12 +1,17 @@
 """Host time by layer, from the program's own spans (``repro.core.spans``).
 
     python -m benchmarks.host_spans [--job bc|coremark] [--scale 9]
-        [--mem-mib 64] [--seed 1] [--slice] [--save PATH]
+        [--mem-mib 64] [--seed 1] [--boards N [--chips K]] [--slice]
+        [--save PATH]
 
 Jobs of a cell-sized deployment on ``JaxTarget``: GAPBS bc, 4 threads, 1
 trial, on an ``rmat(scale, 16)`` weighted Kronecker graph over the
 modelled PCIe link (``FASE_ROCKET_PCIE``), or CoreMark-lite, 1 iteration,
-1 thread, over the UART (``FASE_ROCKET``).  A warm-up job compiles every
+1 thread, over the UART (``FASE_ROCKET``).  With ``--boards N`` a "job"
+is one lockstep round of a fleet instead (``FASE_FLEET_VMAP``,
+``FleetRuntime.run_synchronous``), a job a board, bc's graphs drawn from
+``seed + board``, its stack sharded over ``--chips K`` devices when given
+(``FASE_FLEET_SHARDED``).  A warm-up job compiles every
 program and a second job runs untraced; a third is recorded whole by the
 profiler with the TPU tracer in its light mode (host events only), as
 the cell benchmark records its first unit (``bench/fasebench``).  One
@@ -26,10 +31,14 @@ and ``layer_share`` (the three layers over ``between_chunks``),
 included) beside ``shadow_reads_per_chunk`` (reads ``JaxTarget``
 answered from its state shadow, with no device transfer), the span
 count by name, both jobs' walls and
-the cost of one span with the profiler off and on.  ``--slice`` runs one
+the cost of one span with the profiler off and on; a fleet round adds
+``live_boards_per_chunk``, the boards given a budget per global chunk
+(``FleetTarget.live_board_chunks`` over its launches).  ``--slice`` runs one
 more job with the TPU tracer's full mode over 1 s of it from 1 s in and
 adds the device's idle share and its idle gaps, each labelled by the
-innermost span around its start and the program launched next.
+innermost span around its start and the program launched next, and on a
+fleet over chips the chips' wait on the slowest board
+(``bench/metrics/lockstep_wait_pct.py``).
 ``--save`` writes the light recording, gzipped, for
 ``jax.profiler.ProfileData.from_serialized_xspace``.
 """
@@ -185,8 +194,11 @@ def reduce_slice(data) -> dict:
     gaps = sl.idle_gaps(top=1000)
     idle = sum(s for _, s in gaps)
     named = sum(s for label, s in gaps if label.startswith(PREFIX))
+    from fasebench.spec import metric_reader
     return {"slice_window_s": sl.window_ns / 1e9,
             "device_idle_pct": 100.0 * (1 - sl.busy_ns() / sl.window_ns),
+            "lockstep_wait_pct": metric_reader(
+                ROOT, "lockstep_wait_pct")(xtrace.Trace(slice=sl)),
             "idle_s": idle,
             "idle_share_named": named / idle if idle else None,
             "idle_gaps": gaps[:15]}
@@ -216,6 +228,8 @@ def main(argv=None) -> None:
     ap.add_argument("--scale", type=int, default=9)
     ap.add_argument("--mem-mib", type=int, default=64)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--boards", type=int, default=0)
+    ap.add_argument("--chips", type=int)
     ap.add_argument("--slice", action="store_true")
     ap.add_argument("--save")
     args = ap.parse_args(argv)
@@ -226,26 +240,52 @@ def main(argv=None) -> None:
 
     from fasebench.window import Recording, profile_options
     from repro.compile_cache import enable_compile_cache
-    from repro.configs.fase_rocket import runtime_kwargs, target_kwargs
-    from repro.configs.registry import FASE_ROCKET, FASE_ROCKET_PCIE
+    from repro.configs.fase_rocket import (fleet_kwargs, runtime_kwargs,
+                                           target_kwargs)
+    from repro.configs.registry import (FASE_FLEET_VMAP, FASE_ROCKET,
+                                        FASE_ROCKET_PCIE)
+    from repro.core.fleet import FleetRuntime, Job
     from repro.core.interface import JaxTarget
     from repro.core.runtime import FaseRuntime
     from repro.core.workloads import build, graphgen
     enable_compile_cache()
 
     cfg = FASE_ROCKET_PCIE if args.job == "bc" else FASE_ROCKET
-    argv_, files = [args.job, "1"], {}
+    argv_, files = [args.job, "1"], [{}] * max(args.boards, 1)
     if args.job == "bc":
         argv_ = ["bc", "g.bin", "4", "1"]
-        files = {"g.bin": graphgen.rmat(args.scale, 16, args.seed, True)}
+        files = [{"g.bin": graphgen.rmat(args.scale, 16, args.seed + b,
+                                         True)}
+                 for b in range(max(args.boards, 1))]
+    link = {k: cfg[k] for k in ("link", "qp_depth", "qp_coalesce_ticks")}
+    fleet_cfg = {**FASE_FLEET_VMAP, **link, "n_devices": args.boards,
+                 "mem_bytes": args.mem_mib << 20}
+    if args.chips:
+        fleet_cfg["fleet_chips"] = args.chips
+    live = []
+
+    def fleet_round():
+        """One lockstep round, a job a board."""
+        t = time.perf_counter()
+        with TraceAnnotation(JOB):
+            fleet = FleetRuntime(**fleet_kwargs(fleet_cfg))
+            res = fleet.run_synchronous(
+                [Job(args.job, argv_[1:], files=f, max_ticks=1 << 44)
+                 for f in files], max_ticks=1 << 44)
+            ft = fleet.fleet_target
+            jax.block_until_ready(ft.sts)
+        live[:] = [ft.live_board_chunks / ft.dispatch_count]
+        return [r.report for r in res], time.perf_counter() - t, None
 
     def job():
+        if args.boards:
+            return fleet_round()
         t = time.perf_counter()
         with TraceAnnotation(JOB):
             tgt = JaxTarget(cfg["n_cores"], args.mem_mib << 20,
                             **target_kwargs(cfg))
             rt = FaseRuntime(tgt, mode="fase", **runtime_kwargs(cfg))
-            rt.load(build(args.job), argv_, files=files)
+            rt.load(build(args.job), argv_, files=files[0])
             rep = rt.run(max_ticks=1 << 44)
             jax.block_until_ready(tgt.st)
         return rep, time.perf_counter() - t, tgt.shadow_reads
@@ -260,12 +300,16 @@ def main(argv=None) -> None:
                            "untraced job's")
     if args.save:
         Path(args.save).write_bytes(gzip.compress(raw))
+    instr = sum(sum(r.instret) for r in rep2) if args.boards \
+        else sum(rep2.instret)
     out = {"device": str(jax.devices()[0].device_kind), "job": args.job,
            "untraced_job_s": untraced_s, "traced_job_s": traced_s,
            "span_us_off": span_cost_us(),
            "span_us_on": span_cost_us(profile_options(full=False)),
-           **reduce_job(ProfileData.from_serialized_xspace(raw),
-                        sum(rep.instret), shadow_reads)}
+           **reduce_job(ProfileData.from_serialized_xspace(raw), instr,
+                        shadow_reads)}
+    if args.boards:
+        out["live_boards_per_chunk"] = live[0]
     if args.slice:
         rec = Recording(full=True)
         with rec.over(xtrace.SLICE_START_S, xtrace.SLICE_S):

@@ -83,7 +83,8 @@ def fleet_kwargs(cfg: dict = FASE_FLEET) -> dict:
     ``n_cores``/``mem_bytes`` and target_* knobs, so
     ``FleetRuntime(**fleet_kwargs(cfg))`` builds the stacked
     single-dispatch :class:`~repro.core.fleet.vmap.FleetTarget` with no
-    ``make_target`` at all."""
+    ``make_target`` at all; ``fleet_chips`` (FASE_FLEET_SHARDED) shards
+    that stack over as many chips."""
     out = runtime_kwargs(cfg)
     out.update({k: cfg[k] for k in _FLEET_KEYS if k in cfg})
     out.update({new: cfg[old] for old, new in _FLEET_RENAMED.items()
@@ -94,4 +95,6 @@ def fleet_kwargs(cfg: dict = FASE_FLEET) -> dict:
         out["fleet_vmap"] = True
         out["target_cfg"] = dict(n_cores=cfg["n_cores"],
                                  mem_bytes=cfg["mem_bytes"], **tk)
+        if cfg.get("fleet_chips") is not None:
+            out["target_cfg"]["fleet_chips"] = cfg["fleet_chips"]
     return out

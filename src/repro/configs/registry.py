@@ -118,6 +118,12 @@ FASE_FLEET = {**FASE_ROCKET_PCIE, "n_devices": 4,
 # target_cfg from the config's n_cores/mem_bytes/target_* knobs.
 FASE_FLEET_VMAP = {**FASE_FLEET, "fleet_vmap": True}
 
+# the vmapped fleet over several chips: the stack sharded along the board
+# axis over ``fleet_chips`` devices, each chip holding n_devices /
+# fleet_chips boards (one a chip here, as a FireSim run farm gives each
+# simulated node an FPGA of its own); a global chunk is still one launch
+FASE_FLEET_SHARDED = {**FASE_FLEET_VMAP, "fleet_chips": 4}
+
 # provisioning-aware fleet: bitstream flash + ELF load cost several ms of
 # modelled time per re-image, and the provision-aware least_loaded policy
 # trades that charge off against queue depth (benchmarks/migration.py

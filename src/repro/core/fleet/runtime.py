@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .. import snapshot as snapmod
+from .. import spans
 from ..target.cpu import CLOCK_HZ
 from ..workloads import build
 from .device import Device
@@ -278,7 +279,8 @@ class FleetRuntime:
                     budgets[h.runtime.target.d] = \
                         h.runtime.target.chunk_cycles
             if budgets.any():
-                self.fleet_target.run_global(budgets)
+                with spans.span("chunk"):
+                    self.fleet_target.run_global(budgets)
             for h in handles:
                 if budgets[h.runtime.target.d]:
                     tk = h.runtime.target.get_ticks()  # analysis: allow-host-sync

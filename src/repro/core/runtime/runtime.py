@@ -480,10 +480,8 @@ class FaseRuntime:
         handle every exception the chunk raised, restoring the same
         loop-boundary invariant :meth:`run_slice` keeps (all raised
         exceptions handled, no half-applied host work).  The fleet has
-        launched the chunk and waited on it; the clock read here is this
-        board's end of it (``fase:chunk``)."""
-        with spans.span("chunk"):
-            now = self.target.get_ticks()  # analysis: allow-host-sync
+        launched the chunk and waited on it (``fase:chunk``)."""
+        now = self.target.get_ticks()  # analysis: allow-host-sync
         if self.traffic_hook is not None:
             self.traffic_hook(now)
         if self.telemetry is not None:

@@ -11,7 +11,9 @@ Layer prefixes, so that a reader selects a layer by prefix:
 
 * ``fase:chunk``: one chunk of the target, from its launch to the end of
   the first read after it (on ``JaxTarget``, ``fase:sync:chunk_record``,
-  the read of the chunk's state record, which waits on the chunk);
+  the read of the chunk's state record, which waits on the chunk; on a
+  fleet, one global chunk: ``FleetTarget.run_global``'s launch and its
+  read of every board's clock);
 * ``fase:rt:``: the host runtime (``run``, ``load``, ``finish``,
   ``poll``, ``dispatch``, ``exception``, ``hfutex``, ``syscall``,
   ``sys:<name>``, ``pagefault``);
@@ -21,7 +23,7 @@ Layer prefixes, so that a reader selects a layer by prefix:
   the host (``chunk_record``, ``shadow_fill``, ``fetch_batch``,
   ``page_read``, ``trace_drain``); a read ``JaxTarget`` answers from its
   state shadow has none; ``fase:acc:<accessor>``: one that only
-  launches a program.
+  launches a program.  A fleet's ``FleetTargetView`` uses the same names.
 
 Every call site goes through :func:`span`, so a test may put a recorder
 in its place.

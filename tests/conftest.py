@@ -1,9 +1,18 @@
+import os
 import sys
 from pathlib import Path
 
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
+
+# Four host devices for XLA:CPU, set before any JAX backend starts, so
+# that a fleet sharded over four chips runs here as it does on a
+# four-chip host; flags already set are kept, a device count among them.
+_FLAGS = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _FLAGS:
+    os.environ["XLA_FLAGS"] = \
+        f"{_FLAGS} --xla_force_host_platform_device_count=4".strip()
 
 
 def pytest_configure(config):

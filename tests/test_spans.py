@@ -243,3 +243,43 @@ def test_recorded_chip_trace(recorded):
         "between_chunks_ms_per_kinstr": 12.745715923734386,
         "layer_share": 0.9062359317698773})
     assert 0.9 <= out["layer_share"] <= 1.1
+
+
+@pytest.mark.parametrize("chips", [None, 2])
+def test_fleet_round_spans(chips, monkeypatch):
+    """A fleet round, on one stack or sharded over two chips: one
+    ``fase:chunk`` a global chunk, its accessors under the names
+    ``JaxTarget`` gives its own, and ``live_board_chunks`` counting the
+    boards given a budget."""
+    import re
+
+    from repro.configs.fase_rocket import fleet_kwargs
+    from repro.configs.registry import FASE_FLEET_VMAP
+    from repro.core import interface
+    from repro.core.fleet import FleetRuntime, Job
+
+    names: Counter = Counter()
+
+    def recorder(name):
+        names[spans.PREFIX + name] += 1
+        return contextlib.nullcontext()
+    monkeypatch.setattr(spans, "span", recorder)
+    cfg = {**FASE_FLEET_VMAP, "n_devices": 2, "mem_bytes": 1 << 23}
+    if chips:
+        cfg["fleet_chips"] = chips
+    fleet = FleetRuntime(**fleet_kwargs(cfg))
+    res = fleet.run_synchronous(
+        [Job("bc", ["g.bin", "4", "1"], files={"g.bin": GRAPH}),
+         Job("bc", ["g.bin", "2", "1"], files={"g.bin": GRAPH})])
+    ft = fleet.fleet_target
+    assert names["fase:chunk"] == ft.dispatch_count > 0
+    assert ft.dispatch_count < ft.live_board_chunks <= 2 * ft.dispatch_count
+    assert names["fase:rt:exception"] == \
+        sum(r.report.sched["exceptions"] for r in res)
+    jax_target = set(re.findall(
+        r'spans\.(?:traced|span)\("((?:acc|sync):\w+)"',
+        Path(interface.__file__).read_text()))
+    fleet_names = {n[len(spans.PREFIX):] for n in names
+                   if n.startswith(("fase:acc:", "fase:sync:"))}
+    assert {"sync:fetch_batch", "acc:commit_batch", "acc:redirect"} <= \
+        fleet_names <= jax_target

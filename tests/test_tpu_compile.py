@@ -3,8 +3,9 @@
 The TPU compiler is installed beside the CPU backend, so every program
 ``chip_smoke.py`` dispatches on the chip can be lowered and compiled
 here at ``FASE_ROCKET`` width (4 cores, 64 MiB image; the fleet at 4
-boards): a program the chip's compiler refuses, or one that does not
-fit one chip's 16 GB, fails here instead of on the chip.  Nothing runs.
+boards, and the four-chip farm's sharded chunk at 2 GiB a board): a
+program the chip's compiler refuses, or one that does not fit one chip's
+16 GB, fails here instead of on the chip.  Nothing runs.
 """
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.fase_rocket import target_kwargs
-from repro.configs.registry import FASE_FLEET_VMAP, FASE_ROCKET
+from repro.configs.registry import (FASE_FLEET_SHARDED, FASE_FLEET_VMAP,
+                                    FASE_ROCKET)
 from repro.core.interface import pack_read_batch, pack_write_batch
 from repro.core.target import cpu
 
@@ -25,10 +27,10 @@ HBM_BYTES = 16 * 10**9
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2; the persistent compilation
-    cache is off meanwhile (an entry compiled for a described chip
-    cannot be read back without one)."""
+def topo():
+    """A described v5e:2x2; the persistent compilation cache is off
+    meanwhile (an entry compiled for a described chip cannot be read
+    back without one)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,10 +45,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_on)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _state(sharding, boards=None):
@@ -92,6 +99,34 @@ def test_run_chunk_fleet_compiles_for_v5e(one_chip):
         KW["block_words"], KW["block_cache"], KW["fetch_kernel"],
         KW["dtlb_ways"], BOARDS).compile()
     _fits_one_chip(compiled)
+
+
+def test_sharded_fleet_chunk_compiles_for_v5e_2x2(topo):
+    """The farm's global chunk: four boards at the FASE board's 2 GiB
+    image, one a chip of four, in one program that holds each chip to
+    its own board and needs no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core.fleet import vmap
+    mem, chips = 1 << 31, FASE_FLEET_SHARDED["fleet_chips"]
+    mesh = Mesh(np.array(topo.devices[:chips]), (vmap.BOARD,))
+    sharded = NamedSharding(mesh, PartitionSpec(vmap.BOARD))
+    shapes = jax.eval_shape(lambda: vmap._blank_stack(chips, NC, mem))
+    sts = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded),
+        shapes)._replace(tracebuf=None)
+    budgets = jax.ShapeDtypeStruct((chips,), jnp.uint64, sharding=sharded)
+    compiled = vmap._run_chunk_fleet_shards.lower(
+        sts, budgets, cpu.run_chunk_fleet, mesh, NC, mem,
+        KW["issue_width"], KW["block_words"], KW["block_cache"],
+        KW["fetch_kernel"], KW["dtlb_ways"]).compile()
+    _fits_one_chip(compiled)
+    assert mem <= compiled.memory_analysis().argument_size_in_bytes \
+        < 2 * mem
+    text = compiled.as_text()
+    assert not any(op in text for op in ("all-reduce", "all-gather",
+                                         "collective-permute",
+                                         "all-to-all", "reduce-scatter"))
 
 
 def _spec_tree(sharding, tree):

@@ -29,8 +29,10 @@ JSON line holds, per thousand guest instructions (``_ms_per_kinstr``):
 and ``layer_share`` (the three layers over ``between_chunks``),
 ``host_syncs_per_chunk`` (``fase:sync:`` spans, the chunk record's read
 included) beside ``shadow_reads_per_chunk`` (reads ``JaxTarget``
-answered from its state shadow, with no device transfer), the span
-count by name, both jobs' walls and
+answered from its state shadow, with no device transfer) and
+``image_programs_per_chunk`` (``JaxTarget``'s accessor programs that
+took the memory image, the chunk aside), the span count by name, both
+jobs' walls and
 the cost of one span with the profiler off and on; a fleet round adds
 ``live_boards_per_chunk``, the boards given a budget per global chunk
 (``FleetTarget.live_board_chunks`` over its launches).  ``--slice`` runs one
@@ -148,20 +150,21 @@ def split(spans, kinstr: float) -> dict:
             "host_syncs_per_chunk": syncs / names[CHUNK]}
 
 
-def shadow_split(spans, shadow_reads: int | None) -> dict:
-    """``shadow_reads_per_chunk``: the target's shadow-served reads per
-    ``fase:chunk`` span; empty without a count or a chunk."""
+def per_chunk(spans, counts: dict) -> dict:
+    """``<count>_per_chunk`` for each of the target's counts over the
+    job (``shadow_reads``, ``image_programs``): the count per
+    ``fase:chunk`` span; empty without a chunk."""
     chunks = sum(1 for sp in spans if sp[0] == CHUNK)
-    if shadow_reads is None or not chunks:
+    if not chunks:
         return {}
-    return {"shadow_reads_per_chunk": shadow_reads / chunks}
+    return {f"{name}_per_chunk": n / chunks for name, n in counts.items()}
 
 
-def reduce_job(data, guest_instr: int, shadow_reads: int | None = None
+def reduce_job(data, guest_instr: int, counts: dict | None = None
                ) -> dict:
     """The recorded job (inside its ``JOB`` span) of a light recording;
-    ``shadow_reads``, the target's count for the job, adds
-    ``shadow_reads_per_chunk``."""
+    ``counts``, the target's counts for the job by name, add each per
+    chunk (:func:`per_chunk`)."""
     from fasebench.spec import metric_reader
     (_, t0, t1, _), = program_spans(data, JOB)
     programs, _ = xtrace.host_events(data)
@@ -173,7 +176,7 @@ def reduce_job(data, guest_instr: int, shadow_reads: int | None = None
            "chunk_launches": len(unit.chunks()),
            "other_launches": len(unit.others()),
            **split(spans, unit.kinstr),
-           **shadow_split(spans, shadow_reads),
+           **per_chunk(spans, counts or {}),
            "between_chunks_ms_per_kinstr": metric_reader(
                ROOT, "between_chunks_ms_per_kinstr")(xtrace.Trace(unit))}
     three = [out[f"{k}_ms_per_kinstr"] for k in (*LAYERS, "accessor_host")]
@@ -275,7 +278,7 @@ def main(argv=None) -> None:
             ft = fleet.fleet_target
             jax.block_until_ready(ft.sts)
         live[:] = [ft.live_board_chunks / ft.dispatch_count]
-        return [r.report for r in res], time.perf_counter() - t, None
+        return [r.report for r in res], time.perf_counter() - t, {}
 
     def job():
         if args.boards:
@@ -288,12 +291,14 @@ def main(argv=None) -> None:
             rt.load(build(args.job), argv_, files=files[0])
             rep = rt.run(max_ticks=1 << 44)
             jax.block_until_ready(tgt.st)
-        return rep, time.perf_counter() - t, tgt.shadow_reads
+        return rep, time.perf_counter() - t, {
+            "shadow_reads": tgt.shadow_reads,
+            "image_programs": tgt.image_programs}
 
     job()                                    # warm-up: compiles
     rep, untraced_s, _ = job()
     session = _profiler.ProfilerSession(profile_options(full=False))
-    rep2, traced_s, shadow_reads = job()
+    rep2, traced_s, counts = job()
     raw = session.stop()
     if rep2 != rep:
         raise RuntimeError("the traced job's report differs from the "
@@ -307,7 +312,7 @@ def main(argv=None) -> None:
            "span_us_off": span_cost_us(),
            "span_us_on": span_cost_us(profile_options(full=False)),
            **reduce_job(ProfileData.from_serialized_xspace(raw), instr,
-                        shadow_reads)}
+                        counts)}
     if args.boards:
         out["live_boards_per_chunk"] = live[0]
     if args.slice:

@@ -196,6 +196,13 @@ class JaxTarget:
     ``shadow_reads`` counts the accessor calls it answered with no
     device transfer of their own.
 
+    A program that takes the u64 memory image makes a pass over all of
+    it on the TPU, whether it touches a word or not.  So every accessor
+    program that touches no word (the micro-ops, the batches without
+    words) is given the state without ``mem``, and the image stays
+    where it is; ``image_programs`` counts the accessor programs
+    launched with it, the chunk aside.
+
     ``fast_path`` (default on) selects the batched-issue vectorized
     interpreter with the per-core fetch-block cache
     (:func:`repro.core.target.cpu.run_chunk_fast`); ``fast_path=False``
@@ -236,6 +243,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         self._trace_base: list = []
         self._trigger: tuple | None = None   # capture-window predicate
         self.shadow_reads = 0
+        self.image_programs = 0
         self.st = _cpu.make_state(n_cores, mem_bytes)
 
     # -- the state shadow -------------------------------------------------
@@ -294,6 +302,15 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         if name in _SHADOWED:
             self._put(self._slot(c, name), v)
 
+    def _update(self, op, *args, words: bool = False) -> None:
+        """``op(st, *args)``, donated; the state is given without its
+        memory image unless ``words``, and the image is put back on the
+        result untouched."""
+        st = self._st
+        self.image_programs += words
+        out = op(st if words else st._replace(mem=None), *args)
+        self._st = out if words else out._replace(mem=st.mem)
+
     # -- inst stream ------------------------------------------------------
     @property
     def n_cores(self):
@@ -319,15 +336,15 @@ PySim` — the knobs trade compile time and host speed, never semantics:
     def redirect(self, c, pc, resume_tick=0):
         # one donated jitted dispatch, not four eager scatters
         pc, resume = int(pc), max(resume_tick, 0)
-        self._st = _cpu.redirect_op(self._st, np.int32(c), np.uint64(pc),
-                                    np.uint64(resume))
+        self._update(_cpu.redirect_op, np.int32(c), np.uint64(pc),
+                     np.uint64(resume))
         for name, v in (("pc", pc), ("priv", 0), ("pending", 0),
                         ("stall_until", resume)):
             self._write_through(c, name, v)
 
     @spans.traced("acc:park")
     def park(self, c):
-        self._st = _cpu.park_op(self._st, np.int32(c))
+        self._update(_cpu.park_op, np.int32(c))
         self._write_through(c, "priv", 3)
         self._write_through(c, "pending", 0)
 
@@ -338,7 +355,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
     @spans.traced("acc:clear_pending")
     def clear_pending(self, c):
-        self._st = _cpu.clear_pending_op(self._st, np.int32(c))
+        self._update(_cpu.clear_pending_op, np.int32(c))
         self._write_through(c, "pending", 0)
 
     # -- priv / csr ---------------------------------------------------------
@@ -356,14 +373,12 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         restore).  Each field keeps its device dtype; ``ticks`` is the
         global clock scalar.  One jitted donated dispatch per write."""
         v = int(v) & _MASK64
-        self._st = _cpu.csr_write_op(self._st, name, np.int32(c),
-                                     np.uint64(v))
+        self._update(_cpu.csr_write_op, name, np.int32(c), np.uint64(v))
         self._write_through(c, name, v)
 
     @spans.traced("acc:set_satp")
     def set_satp(self, c, v):
-        self._st = _cpu.csr_write_op(self._st, "satp", np.int32(c),
-                                     np.uint64(v))
+        self._update(_cpu.csr_write_op, "satp", np.int32(c), np.uint64(v))
         self._write_through(c, "satp", int(v))
 
     def sfence(self, c):
@@ -388,7 +403,8 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         device gather (``fase:sync:fetch_batch``,
         :func:`repro.core.target.cpu.fetch_read_batch`), its index
         arrays pow2-padded so that a handful of compiled shapes serve
-        every request mix."""
+        every request mix; a gather of no word is given the state
+        without its image."""
         regs, csrs, words = list(regs), list(csrs), list(words)
         far = [(c, n) for c, n in csrs if n not in _SHADOWED]
         sh = None
@@ -399,8 +415,10 @@ PySim` — the knobs trade compile time and host speed, never semantics:
             with spans.span("sync:fetch_batch"):
                 names, reg_cpu, reg_idx, word_idx, csr_cpus, order = \
                     pack_read_batch((), far, words)
+                self.image_programs += bool(words)
                 got = jax.device_get(_cpu.fetch_read_batch(
-                    self._st, names, reg_cpu, reg_idx, word_idx, csr_cpus))
+                    self._st if words else self._st._replace(mem=None),
+                    names, reg_cpu, reg_idx, word_idx, csr_cpus))
             _, far_vals, wv = unpack_read_batch(got, 0, len(words), names,
                                                 order)
         far_it = iter(far_vals)
@@ -412,8 +430,8 @@ PySim` — the knobs trade compile time and host speed, never semantics:
     def reg_write(self, c, idx, v):
         if idx != 0:
             v = int(v) & _MASK64
-            self._st = _cpu.reg_write_op(self._st, np.int32(c),
-                                         np.int32(idx), np.uint64(v))
+            self._update(_cpu.reg_write_op, np.int32(c), np.int32(idx),
+                         np.uint64(v))
             self._put(self._reg_slot(c, idx), v)
 
     @spans.traced("acc:commit_batch")
@@ -429,13 +447,14 @@ PySim` — the knobs trade compile time and host speed, never semantics:
         pow2-padded with out-of-bounds drop sentinels so a handful of
         shapes serve every transaction.  Bit-identical to replaying the
         per-element accessors in order; registers and fields go into the
-        shadow too."""
-        regs, csrs = list(regs), list(csrs)
+        shadow too.  A batch of no word is given the state without its
+        image."""
+        regs, csrs, words = list(regs), list(csrs), list(words)
         packed = pack_write_batch(self.nc, self.mem_bytes >> 3,
                                   regs, csrs, words)
         if packed is None:
             return
-        self._st = _cpu.apply_write_batch(self._st, *packed)
+        self._update(_cpu.apply_write_batch, *packed, words=bool(words))
         for c, idx, v in regs:
             self._put(self._reg_slot(c, idx), int(v))
         for c, name, v in csrs:
@@ -447,6 +466,7 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
     @spans.traced("acc:mem_write_word")
     def mem_write_word(self, pa, v):
+        self.image_programs += 1
         self._st = self._st._replace(
             mem=_cpu.mem_write_words(self._st.mem,
                                      jnp.asarray([pa >> 3]),
@@ -454,23 +474,27 @@ PySim` — the knobs trade compile time and host speed, never semantics:
 
     @spans.traced("sync:page_read")
     def page_read(self, ppn):
+        self.image_programs += 1
         return np.asarray(_cpu.page_read_words(self._st.mem,
                                                (ppn << 12) >> 3))
 
     @spans.traced("acc:page_write")
     def page_write(self, ppn, words):
         w = jnp.asarray(np.ascontiguousarray(words, dtype=np.uint64))
+        self.image_programs += 1
         self._st = self._st._replace(
             mem=_cpu.page_write_words(self._st.mem, (ppn << 12) >> 3, w))
 
     @spans.traced("acc:page_set")
     def page_set(self, ppn, val):
+        self.image_programs += 1
         self._st = self._st._replace(
             mem=_cpu.page_set_words(self._st.mem, (ppn << 12) >> 3,
                                     np.uint64(val)))
 
     @spans.traced("acc:page_copy")
     def page_copy(self, src_ppn, dst_ppn):
+        self.image_programs += 2          # a slice, then an update
         self._st = self._st._replace(
             mem=_cpu.page_copy_words(self._st.mem, (src_ppn << 12) >> 3,
                                      (dst_ppn << 12) >> 3))

@@ -1334,12 +1334,14 @@ def apply_write_batch(st: CpuState, csr_names: tuple,
     ``csr_names`` is a static sorted tuple of the CSR names present;
     ``csr_cpus``/``csr_vals`` are matching tuples of (cpu-index, value)
     arrays, one pair per name, since each CSR targets a different
-    :class:`CpuState` field with its own dtype story.
+    :class:`CpuState` field with its own dtype story.  ``st.mem`` is
+    None where the batch writes no word.
     """
-    regs = st.regs.at[reg_cpu, reg_idx].set(
-        jnp.asarray(reg_val, U64), mode="drop")
-    mem = st.mem.at[word_idx].set(jnp.asarray(word_val, U64), mode="drop")
-    st = st._replace(regs=regs, mem=mem)
+    st = st._replace(regs=st.regs.at[reg_cpu, reg_idx].set(
+        jnp.asarray(reg_val, U64), mode="drop"))
+    if st.mem is not None:
+        st = st._replace(mem=st.mem.at[word_idx].set(
+            jnp.asarray(word_val, U64), mode="drop"))
     for name, cc, vv in zip(csr_names, csr_cpus, csr_vals):
         vv = jnp.asarray(vv, U64)
         if name == "pending":
@@ -1357,7 +1359,11 @@ def apply_write_batch(st: CpuState, csr_names: tuple,
 # eager by design (Redirect / Next's clear-pending / park / the ticks
 # clock) are each ONE donated dispatch instead of a handful of
 # un-jitted scatter primitives — the same dispatch-count discipline as
-# the batched read/write paths, for ops too small to batch.
+# the batched read/write paths, for ops too small to batch.  None
+# touches a memory word, so JaxTarget gives them the state without its
+# image, as FleetTargetView gives their _fleet_* twins the stack: a
+# program that takes the u64 image makes a pass over all of it on the
+# TPU, whether or not it reads a word.
 # ---------------------------------------------------------------------------
 @partial(jax.jit, donate_argnums=(0,))
 def redirect_op(st: CpuState, c, pc, resume) -> CpuState:
@@ -1411,9 +1417,12 @@ def fetch_read_batch(st: CpuState, csr_names: tuple,
     of the CSR/core-state fields present; ``csr_cpus`` the matching
     tuple of cpu-index arrays.  Every CSR value is widened to u64
     (``pending`` -> 0/1, ``priv`` zero-extended, ``ticks`` broadcast
-    from the global scalar), matching the per-element accessors."""
+    from the global scalar), matching the per-element accessors.
+    ``st.mem`` is None where the batch reads no word; the words then
+    read 0."""
     regs = st.regs[reg_cpu, reg_idx]
-    words = st.mem[word_idx]
+    words = st.mem[word_idx] if st.mem is not None \
+        else jnp.zeros(word_idx.shape, U64)
     csr_out = []
     for name, cc in zip(csr_names, csr_cpus):
         if name == "ticks":

@@ -15,8 +15,9 @@ import pytest
 from jax._src.lib import _profiler
 from jax.profiler import ProfileData
 
-from benchmarks.host_spans import (JOB, outside_chunks_ns, program_spans,
-                                   reduce_job, self_ns, split, xtrace)
+from benchmarks.host_spans import (JOB, outside_chunks_ns, per_chunk,
+                                   program_spans, reduce_job, self_ns,
+                                   split, xtrace)
 from repro.configs.fase_rocket import runtime_kwargs, target_kwargs
 from repro.configs.registry import FASE_ROCKET_PCIE
 from repro.core import spans
@@ -126,6 +127,17 @@ def test_shadow_serves_the_per_core_reads(recorded):
         pytest.approx(1.4666666666666666)
 
 
+def test_image_programs_are_the_word_programs(recorded):
+    """Of the job's accessor programs only those that touch a memory
+    word take the image: its 55 reads, all of words, and 23 of its 104
+    write batches; the micro-ops and the other 81 batches run without
+    it."""
+    names, _, rt, _ = recorded
+    assert names["fase:sync:fetch_batch"] == 55
+    assert names["fase:acc:commit_batch"] == 104
+    assert rt.target.image_programs == 55 + 23
+
+
 def test_spans_leave_results_alone(plain_report):
     session = _profiler.ProfilerSession(profile_options())
     try:
@@ -194,6 +206,15 @@ def test_spans_on_two_threads_nest_apart():
     assert outside_chunks_ns([sp("sync:fetch_batch", 0, 10),
                               sp("acc:park", 5, 8)],
                              ("fase:sync:", "fase:acc:")) == 10
+
+
+def test_target_counts_per_chunk():
+    spans_ = [sp("chunk", 0, 10), sp("acc:redirect", 12, 14),
+              sp("chunk", 20, 30), sp("sync:chunk_record", 25, 30)]
+    assert per_chunk(spans_, {"shadow_reads": 9, "image_programs": 3}) \
+        == {"shadow_reads_per_chunk": 4.5, "image_programs_per_chunk": 1.5}
+    assert per_chunk(spans_, {}) == {}
+    assert per_chunk([sp("rt:run", 0, 10)], {"image_programs": 3}) == {}
 
 
 def test_no_chunk_span_reads_nothing():

@@ -225,3 +225,38 @@ def test_writes_after_a_chunk_and_replaced_state():
     t.trace_arm(4)
     assert t.fetch_batch(regs=[(0, 3)], csrs=[(0, "ticks")])[:2] == \
         ([7], [99])
+
+
+def test_wordless_accessors_leave_the_image_alone():
+    """Every accessor that touches no memory word runs without the image:
+    the state's ``mem`` is the very same array afterwards and
+    ``image_programs`` stays put; one word write moves it by one.  The
+    state then equals ``PySim``'s after the same calls."""
+    t, ref = CheckedTarget(2, 1 << 16), PySim(2, 1 << 16)
+    mem, before = t.st.mem, t.image_programs
+    for tgt in (t, ref):
+        tgt.redirect(0, 0x40, resume_tick=9)
+        tgt.park(1)
+        tgt.clear_pending(0)
+        tgt.csr_write(1, "mepc", 0x1234)
+        tgt.set_satp(0, 8 << 60)
+        tgt.reg_write(1, 5, 77)
+        tgt.commit_batch(regs=[(0, 2, 5)], csrs=[(1, "mcause", 8),
+                                                 (0, "pending", 1)])
+        assert tgt.fetch_batch(csrs=[(0, "stall_ticks"),
+                                     (1, "fetch_walks")])[1] == [0, 0]
+    assert t.st.mem is mem and t.image_programs == before
+    for tgt in (t, ref):
+        tgt.commit_batch(words=[(3, 0xabc)])
+    assert t.image_programs == before + 1
+    fields = ("pc", "priv", "pending", "stall_until", "satp", "mcause",
+              "mepc", "mtval", "res", "uticks", "instret", "stall_ticks")
+    for c in range(2):
+        assert [t.reg_read(c, i) for i in range(32)] == \
+            [ref.reg_read(c, i) for i in range(32)]
+        assert [t.csr_read(c, f) for f in fields] == \
+            [int(ref.csr_read(c, f)) & ((1 << 64) - 1) for f in fields]
+    assert t.get_ticks() == ref.get_ticks()
+    assert t.pending_cores() == ref.pending_cores() == [0]
+    np.testing.assert_array_equal(
+        np.asarray(t.st.mem), np.frombuffer(bytes(ref.mem), np.uint64))

@@ -5,7 +5,9 @@ The TPU compiler is installed beside the CPU backend, so every program
 here at ``FASE_ROCKET`` width (4 cores, 64 MiB image; the fleet at 4
 boards, and the four-chip farm's sharded chunk at 2 GiB a board): a
 program the chip's compiler refuses, or one that does not fit one chip's
-16 GB, fails here instead of on the chip.  Nothing runs.
+16 GB, fails here instead of on the chip.  ``JaxTarget``'s programs that
+touch no memory word, given the state without its image at the board's
+2 GiB, must take next to no device memory.  Nothing runs.
 """
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,8 @@ KW = target_kwargs(FASE_ROCKET)
 BOARDS = FASE_FLEET_VMAP["n_devices"]
 #: HBM of one v5e chip (Google Cloud documentation, "TPU v5e")
 HBM_BYTES = 16 * 10**9
+#: the FASE board's DDR4, held whole as the image
+BOARD_MEM = 1 << 31
 
 
 @pytest.fixture(scope="module")
@@ -56,8 +60,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _state(sharding, boards=None):
-    shapes = jax.eval_shape(lambda: cpu.make_state(NC, MEM))
+def _state(sharding, boards=None, mem=MEM):
+    shapes = jax.eval_shape(lambda: cpu.make_state(NC, mem))
     lead = () if boards is None else (boards,)
     return jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
@@ -154,9 +158,15 @@ def _read_batch(one_chip):
         *_spec_tree(one_chip, (reg_cpu, reg_idx, word_idx, csr_cpus)))
 
 
+def _no_image(one_chip):
+    """The state as ``JaxTarget`` gives it to a program that touches no
+    memory word: without its image, here of the board's 2 GiB."""
+    return _state(one_chip, mem=BOARD_MEM)._replace(mem=None)
+
+
 def _redirect(one_chip):
     return cpu.redirect_op.lower(
-        _state(one_chip),
+        _no_image(one_chip),
         *_spec_tree(one_chip, (np.int32(0), np.uint64(0x1000),
                                np.uint64(0))))
 
@@ -165,9 +175,64 @@ def _state_record(one_chip):
     return cpu.state_record.lower(_state(one_chip))
 
 
-@pytest.mark.parametrize("lower", [_write_batch, _read_batch, _redirect,
-                                   _state_record],
-                         ids=["apply_write_batch", "fetch_read_batch",
-                              "redirect_op", "state_record"])
+def _clear_pending(one_chip):
+    return cpu.clear_pending_op.lower(_no_image(one_chip),
+                                      *_spec_tree(one_chip, (np.int32(1),)))
+
+
+def _park(one_chip):
+    return cpu.park_op.lower(_no_image(one_chip),
+                             *_spec_tree(one_chip, (np.int32(1),)))
+
+
+def _csr_write(one_chip):
+    return cpu.csr_write_op.lower(
+        _no_image(one_chip), "mepc",
+        *_spec_tree(one_chip, (np.int32(1), np.uint64(0x1000))))
+
+
+def _reg_write(one_chip):
+    return cpu.reg_write_op.lower(
+        _no_image(one_chip),
+        *_spec_tree(one_chip, (np.int32(1), np.int32(10), np.uint64(7))))
+
+
+def _wordless_write_batch(one_chip):
+    # a syscall's return: a0, the trap CSRs and pending
+    names, *arrays = pack_write_batch(
+        NC, BOARD_MEM >> 3, regs=[(1, 10, 0)],
+        csrs=[(1, "mepc", 0x1004), (1, "pending", 0)])
+    return cpu.apply_write_batch.lower(_no_image(one_chip), names,
+                                       *_spec_tree(one_chip, arrays))
+
+
+def _wordless_read_batch(one_chip):
+    # telemetry counters, which the state shadow does not hold
+    names, reg_cpu, reg_idx, word_idx, csr_cpus, _ = pack_read_batch(
+        csrs=[(c, "stall_ticks") for c in range(NC)])
+    return cpu.fetch_read_batch.lower(
+        _no_image(one_chip), names,
+        *_spec_tree(one_chip, (reg_cpu, reg_idx, word_idx, csr_cpus)))
+
+
+#: the programs given the state without its image
+NO_IMAGE = (_redirect, _clear_pending, _park, _csr_write, _reg_write,
+            _wordless_write_batch, _wordless_read_batch)
+
+
+@pytest.mark.parametrize(
+    "lower",
+    [_write_batch, _read_batch, _redirect, _state_record, _clear_pending,
+     _park, _csr_write, _reg_write, _wordless_write_batch,
+     _wordless_read_batch],
+    ids=["apply_write_batch", "fetch_read_batch", "redirect_op",
+         "state_record", "clear_pending_op", "park_op", "csr_write_op",
+         "reg_write_op", "apply_write_batch_no_words",
+         "fetch_read_batch_no_words"])
 def test_host_micro_op_compiles_for_v5e(one_chip, lower):
-    _fits_one_chip(lower(one_chip).compile())
+    compiled = lower(one_chip).compile()
+    _fits_one_chip(compiled)
+    if lower in NO_IMAGE:
+        m = compiled.memory_analysis()
+        assert m.argument_size_in_bytes < 1 << 20
+        assert m.temp_size_in_bytes < 1 << 20
